@@ -454,7 +454,7 @@ import dataclasses, json, sys, time, zlib
 import numpy as np
 import jax
 from repro.configs.base import get_config
-from repro.launch.mesh import make_rules
+from repro.launch.mesh import make_mesh, make_rules
 from repro.models import api
 from repro.serve.engine import PagedEngine, Request
 
@@ -463,7 +463,7 @@ arch, n_requests, backend = sys.argv[2], int(sys.argv[3]), sys.argv[4]
 cfg = dataclasses.replace(get_config(arch).smoke(), softmax_mode="exact",
                           norm_mode="exact", logit_int8=False)
 params, axes = api.init_params(jax.random.PRNGKey(0), cfg)
-rules = make_rules(jax.make_mesh(shape, ("data", "model")))
+rules = make_rules(make_mesh(shape, ("data", "model")))
 eng = PagedEngine(cfg, params, num_blocks=25, block_size=8, max_seq_len=64,
                   max_running=6, decode_batch=6, prefill_chunk=8,
                   decode_horizon=8, backend=backend, rules=rules,
@@ -496,6 +496,12 @@ print("MESH-RESULT " + json.dumps({
 def run_mesh_shapes(shapes, *, n_requests=6, backend="pallas",
                     timeout=900):
     """{"RxC": row} tok/s per mesh shape, one subprocess per shape."""
+    if jax.default_backend() == "tpu":
+        # this process holds the chip, so a child could never get it
+        raise RuntimeError(
+            "run_mesh_shapes simulates meshes on host devices in child "
+            "processes and cannot run on a TPU host; use "
+            "`python chip_smoke.py --four-chips` for the chip mesh path")
     rows = {}
     for r, c in shapes:
         tag = f"{r}x{c}"

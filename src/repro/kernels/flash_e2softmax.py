@@ -223,8 +223,8 @@ def _paged_kernel(meta_ref, table_ref, kvmap_ref, q_ref, k_ref, v_ref,
     @pl.when(run)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32) * scale    # (bq, d)
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (bs, d)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)            # (bs, d)
+        v = v_ref[0, 0].astype(jnp.float32)
         if kv_scale is not None:                       # int8 page pools
             k = k * kv_scale
             # quant_pv (W8A8): P·V accumulates the raw int8 V codes —
@@ -280,7 +280,10 @@ def flash_e2softmax_paged(q, k_pool, v_pool, tables, meta, *,
       q: (B, H, C, d) — C query tokens per sequence (a prefill chunk, or
         C=1 for decode). GQA is handled inside the index maps (no head
         repeat is materialized).
-      k_pool, v_pool: (N, block_size, KV, d) — the shared page pool.
+      k_pool, v_pool: (N, KV, block_size, d) — the shared page pool,
+        head-major so one (page, head) block is a whole (block_size, d)
+        tile (Mosaic needs a block's last two dims to equal the array's
+        or be (8, 128)-aligned).
       tables: (B, NB) int32 per-sequence page tables; unused slots must
         hold 0 (the reserved null page) so gathers stay in bounds.
       meta: (B, 2) int32 rows (q_start, kv_len) — absolute position of
@@ -297,7 +300,7 @@ def flash_e2softmax_paged(q, k_pool, v_pool, tables, meta, *,
     """
     interpret = resolve_interpret(interpret)
     bsz, h, c, d = q.shape
-    n, bs, kvh, _ = k_pool.shape
+    n, kvh, bs, _ = k_pool.shape
     nb = tables.shape[1]
     if kv_head_map is None:
         kv_head_map = jnp.arange(h, dtype=jnp.int32) // max(h // kvh, 1)
@@ -307,12 +310,12 @@ def flash_e2softmax_paged(q, k_pool, v_pool, tables, meta, *,
         in_specs=[
             pl.BlockSpec((1, 1, c, d),
                          lambda b, hh, j, meta, tbl, kvm: (b, hh, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
+            pl.BlockSpec((1, 1, bs, d),
                          lambda b, hh, j, meta, tbl, kvm:
-                         (tbl[b, j], 0, kvm[hh], 0)),
-            pl.BlockSpec((1, bs, 1, d),
+                         (tbl[b, j], kvm[hh], 0, 0)),
+            pl.BlockSpec((1, 1, bs, d),
                          lambda b, hh, j, meta, tbl, kvm:
-                         (tbl[b, j], 0, kvm[hh], 0)),
+                         (tbl[b, j], kvm[hh], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, c, d),
                                lambda b, hh, j, meta, tbl, kvm: (b, hh, 0, 0)),

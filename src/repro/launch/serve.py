@@ -47,10 +47,10 @@ import argparse
 import dataclasses
 import time
 
-import jax
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh, make_rules
 from repro.models import api
 from repro.serve.engine import Engine, PagedEngine, Request
@@ -133,6 +133,7 @@ def main() -> None:
                          "only; params are shared)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -148,7 +149,8 @@ def main() -> None:
     else:
         rules = None
 
-    params, param_axes = api.init_params(jax.random.PRNGKey(args.seed), cfg)
+    # with a mesh, params are initialised already laid out per the rules
+    params, param_axes = api.init_params(args.seed, cfg, rules)
     rng = np.random.default_rng(args.seed)
     eos_ids = tuple(int(t) for t in args.eos_ids.split(",") if t.strip())
     # encdec requests carry synthetic encoder frames (the paged engine

@@ -34,18 +34,33 @@ def get_model(cfg: ArchConfig):
     return importlib.import_module(_FAMILY_MODULES[cfg.family])
 
 
-def init_params(rng, cfg: ArchConfig):
+def init_params(rng, cfg: ArchConfig, rules=None):
     """Returns (param value tree, logical-axes tree).
 
     ``rng`` is a PRNG key, or a plain int seed — key construction lives
     here so callers outside the sampling contract (serve/, notably)
     never touch ``jax.random.PRNGKey`` themselves (lint rule RPR004).
+
+    With ``rules`` (sharding/rules.py) the init runs as one jitted
+    program whose outputs are laid out per the logical axes, so every
+    device only ever holds its own shard — a model sized for a mesh is
+    never materialized whole on one device first.
     """
-    import jax
+    from repro.models.layers import split_params
     if isinstance(rng, int):
         rng = jax.random.PRNGKey(rng)
-    from repro.models.layers import split_params
-    return split_params(get_model(cfg).init(rng, cfg))
+
+    def init(key):
+        return get_model(cfg).init(key, cfg)
+
+    if rules is None:
+        return split_params(init(rng))
+    shapes, axes = split_params(jax.eval_shape(init, rng))
+    shardings = jax.tree.map(lambda s, ax: rules.sharding(ax, s.shape),
+                             shapes, axes)
+    params = jax.jit(lambda key: split_params(init(key))[0],
+                     out_shardings=shardings)(rng)
+    return params, axes
 
 
 def forward(params, batch: Dict[str, Array], cfg: ArchConfig,
@@ -163,8 +178,8 @@ def paged_decode_inputs(cfg: ArchConfig, shape: ShapeConfig,
     if spec.has_pages:
         cross_blocks = cdiv(spec.cross_tokens, block_size)
         num_blocks = b * (cdiv(s, block_size) + cross_blocks) + 1
-        pool_shape = (spec.kv_layers, num_blocks, block_size,
-                      cfg.n_kv_heads, cfg.head_dim)
+        pool_shape = (spec.kv_layers, num_blocks, cfg.n_kv_heads,
+                      block_size, cfg.head_dim)
         dt = kv_store_dtype(cfg)
         state["k"] = _sds(pool_shape, dt)
         state["v"] = _sds(pool_shape, dt)

@@ -773,7 +773,7 @@ def paged_attend(q: Array, pool_k: Array, pool_v: Array, tables: Array,
                  causal: bool, backend: Optional[str] = None) -> Array:
     """Attention for C chunk queries per sequence against paged KV.
 
-    q: (B, C, H, hd); pool_k/pool_v: (N, bs, KV, hd) one layer's pool
+    q: (B, C, H, hd); pool_k/pool_v: (N, KV, bs, hd) one layer's pool
     (the chunk's own K/V already written); tables: (B, NB) page tables;
     q_start/kv_len: (B,) absolute position of q row 0 / valid key count.
 
@@ -800,7 +800,7 @@ def paged_attend(q: Array, pool_k: Array, pool_v: Array, tables: Array,
     from repro.sharding.rules import active_rules
     rules = active_rules()
     plan = None if rules is None else _paged_tp_plan(
-        rules, q.shape[2], pool_k.shape[2])
+        rules, q.shape[2], pool_k.shape[1])
     if plan is None:
         return fn(q, pool_k, pool_v, tables, q_start, kv_len, **kw)
     return _paged_attend_tp(fn, q, pool_k, pool_v, tables, q_start, kv_len,
@@ -844,7 +844,7 @@ def _paged_attend_tp(fn, q, pool_k, pool_v, tables, q_start, kv_len,
     """
     from repro.sharding.rules import SHARD_MAP_NOCHECK, shard_map
     axes, kv_sharded = plan
-    h, kvh = q.shape[2], pool_k.shape[2]
+    h, kvh = q.shape[2], pool_k.shape[1]
     g = max(h // max(kvh, 1), 1)
     names = axes if isinstance(axes, tuple) else (axes,)
     sizes = [rules.axis_sizes[a] for a in names]
@@ -863,7 +863,7 @@ def _paged_attend_tp(fn, q, pool_k, pool_v, tables, q_start, kv_len,
 
     from jax.sharding import PartitionSpec as P
     qspec = P(None, None, axes, None)
-    kvspec = P(None, None, axes if kv_sharded else None, None)
+    kvspec = P(None, axes if kv_sharded else None, None, None)
     wrapped = shard_map(body, mesh=rules.mesh,
                         in_specs=(qspec, kvspec, kvspec, P(), P(), P()),
                         out_specs=qspec, **SHARD_MAP_NOCHECK)

@@ -386,7 +386,7 @@ def _forward_paged(params, tokens, positions, n_valid, kv_len, refs, state,
         pk = constrain(state["k"], *PAGED_KV_AXES["k"])
         pv = constrain(state["v"], *PAGED_KV_AXES["v"])
         tables = refs["tables"]
-        block_size = pk.shape[2]
+        block_size = pk.shape[3]
         block_ids, offsets = slots_for_positions(positions, block_size,
                                                  tables)
         write_end = (q_start + n_valid)[:, None]

@@ -216,7 +216,7 @@ def _paged_forward(params, tokens, positions, n_valid, kv_len, tables,
     tokens/positions: (B, C) — absolute positions (a prefill chunk, or
     C=1 for decode); n_valid: (B,) real (non-padded) tokens in this
     chunk; kv_len: (B,) valid keys after this chunk's writes;
-    tables: (B, NB) page tables; pools: {"k","v"} (L, N, bs, KV, hd).
+    tables: (B, NB) page tables; pools: {"k","v"} (L, N, KV, bs, hd).
 
     Each layer writes the chunk's K/V into its pages *before* attending,
     so queries see themselves through the same page-table path as the
@@ -254,7 +254,7 @@ def _paged_forward(params, tokens, positions, n_valid, kv_len, tables,
     # stable sharding instead of letting GSPMD re-derive it per call.
     pk = constrain(pools["k"], *PAGED_KV_AXES["k"])
     pv = constrain(pools["v"], *PAGED_KV_AXES["v"])
-    block_size = pk.shape[2]
+    block_size = pk.shape[3]
     block_ids, offsets = slots_for_positions(positions, block_size, tables)
     # mask padded-tail writes to the null page (page 0): positions at or
     # beyond q_start + n_valid hold no real token.

@@ -248,7 +248,7 @@ def encode_paged(params, frames: Array, cross_table: Array, state,
         enc_ctx = jnp.pad(enc_ctx, ((0, 0), (0, cfg.cross_len - valid),
                                     (0, 0)))
     pk, pv = state["k"], state["v"]
-    bs = pk.shape[2]
+    bs = pk.shape[3]
     positions = jnp.broadcast_to(jnp.arange(cfg.cross_len)[None],
                                  (frames.shape[0], cfg.cross_len))
     block_ids, offsets = slots_for_positions(positions, bs, cross_table)
@@ -283,7 +283,7 @@ def _forward_paged(params, tokens, positions, n_valid, kv_len, refs, state,
     pk = constrain(state["k"], *PAGED_KV_AXES["k"])
     pv = constrain(state["v"], *PAGED_KV_AXES["v"])
     tables = refs["tables"]
-    bs = pk.shape[2]
+    bs = pk.shape[3]
     x = L.embed_tokens(params["embed"], tokens, cfg)
     # per-lane sinusoidal positions — same rows _sin_pos builds
     d = cfg.d_model

@@ -19,9 +19,10 @@ Typical model-code usage::
     x, h  = ops.residual_norm_fn("layernorm", mode, cfg)(x, r, gamma, beta)
 
 ``resolve(op, mode, backend)`` is the strict, explicit entry point;
-the ``*_fn`` helpers add the config-driven backend resolution (with
-graceful fallback to ``reference`` when a combination has no kernel —
-the mode is never silently changed, only the execution engine).
+the ``*_fn`` helpers add the config-driven backend resolution (``auto``
+falls back to ``reference`` when a combination has no kernel — the mode
+is never changed, only the execution engine; a forced backend with no
+kernel raises).
 """
 from __future__ import annotations
 

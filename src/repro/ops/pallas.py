@@ -14,11 +14,21 @@ import jax
 import jax.numpy as jnp
 
 from repro.ops import registry
+from repro.sharding.rules import unpartitioned
 
 Array = jax.Array
 
 
-@registry.register("softmax", "sole", "pallas")
+def _kernel(op: str, mode: str):
+    """Register ``fn`` as the (op, mode) pallas implementation, made
+    runnable under a mesh (a Mosaic call cannot be auto-partitioned)."""
+    def deco(fn):
+        registry.register(op, mode, "pallas")(unpartitioned(fn))
+        return fn
+    return deco
+
+
+@_kernel("softmax", "sole")
 def sole_softmax_pallas(x, *, axis: int = -1, mask=None, exp_bits: int = 4,
                         input_scale=None, interpret: Optional[bool] = None,
                         block_rows: int = 256):
@@ -34,7 +44,7 @@ def sole_softmax_pallas(x, *, axis: int = -1, mask=None, exp_bits: int = 4,
                             block_rows=block_rows, interpret=interpret)
 
 
-@registry.register("layernorm", "sole", "pallas")
+@_kernel("layernorm", "sole")
 def sole_layernorm_pallas(x, gamma, beta, *, params=None,
                           interpret: Optional[bool] = None, **kw):
     from repro.kernels.ailayernorm import ailayernorm_pallas
@@ -42,14 +52,14 @@ def sole_layernorm_pallas(x, gamma, beta, *, params=None,
                               interpret=interpret)
 
 
-@registry.register("rmsnorm", "sole", "pallas")
+@_kernel("rmsnorm", "sole")
 def sole_rmsnorm_pallas(x, gamma, *, params=None,
                         interpret: Optional[bool] = None, **kw):
     from repro.kernels.ailayernorm import airmsnorm_pallas
     return airmsnorm_pallas(x, gamma, params=params, interpret=interpret)
 
 
-@registry.register("residual_layernorm", "sole", "pallas")
+@_kernel("residual_layernorm", "sole")
 def sole_residual_layernorm_pallas(x, r, gamma, beta=None, *, params=None,
                                    interpret: Optional[bool] = None, **kw):
     from repro.kernels.ailayernorm import fused_add_norm_pallas
@@ -57,7 +67,7 @@ def sole_residual_layernorm_pallas(x, r, gamma, beta=None, *, params=None,
                                  rms=False, interpret=interpret)
 
 
-@registry.register("residual_rmsnorm", "sole", "pallas")
+@_kernel("residual_rmsnorm", "sole")
 def sole_residual_rmsnorm_pallas(x, r, gamma, beta=None, *, params=None,
                                  interpret: Optional[bool] = None, **kw):
     from repro.kernels.ailayernorm import fused_add_norm_pallas
@@ -65,7 +75,7 @@ def sole_residual_rmsnorm_pallas(x, r, gamma, beta=None, *, params=None,
                                  rms=True, interpret=interpret)
 
 
-@registry.register("residual_layernorm_q", "sole", "pallas")
+@_kernel("residual_layernorm_q", "sole")
 def sole_residual_layernorm_q_pallas(x, r, gamma, beta=None, *, params=None,
                                      interpret: Optional[bool] = None, **kw):
     """Fused residual-add + AILayerNorm + quantize-out: returns
@@ -75,7 +85,7 @@ def sole_residual_layernorm_q_pallas(x, r, gamma, beta=None, *, params=None,
                                        rms=False, interpret=interpret)
 
 
-@registry.register("residual_rmsnorm_q", "sole", "pallas")
+@_kernel("residual_rmsnorm_q", "sole")
 def sole_residual_rmsnorm_q_pallas(x, r, gamma, beta=None, *, params=None,
                                    interpret: Optional[bool] = None, **kw):
     from repro.kernels.ailayernorm import fused_add_norm_quant_pallas
@@ -83,7 +93,7 @@ def sole_residual_rmsnorm_q_pallas(x, r, gamma, beta=None, *, params=None,
                                        rms=True, interpret=interpret)
 
 
-@registry.register("matmul", "w8a8", "pallas")
+@_kernel("matmul", "w8a8")
 def w8a8_matmul_pallas(x, w, *, n_contract: int = 1,
                        interpret: Optional[bool] = None, **kw):
     """int8 x int8 through the blocked MXU kernel. Contraction axes are
@@ -140,9 +150,9 @@ def _flash_attention(sole: bool):
     return fn
 
 
-registry.register("flash_attention", "exact", "pallas")(
+_kernel("flash_attention", "exact")(
     _flash_attention(sole=False))
-registry.register("flash_attention", "sole", "pallas")(
+_kernel("flash_attention", "sole")(
     _flash_attention(sole=True))
 
 
@@ -169,7 +179,7 @@ def _paged_attention(sole: bool):
     return fn
 
 
-registry.register("paged_attention", "exact", "pallas")(
+_kernel("paged_attention", "exact")(
     _paged_attention(sole=False))
-registry.register("paged_attention", "sole", "pallas")(
+_kernel("paged_attention", "sole")(
     _paged_attention(sole=True))

@@ -232,7 +232,7 @@ def _paged_attention_ref(mode: str):
         path — the oracle for paged-vs-dense equivalence tests and the
         fallback for softmax modes the paged kernel does not implement.
 
-        q: (B, C, H, hd); pool_k/pool_v: (N, bs, KV, hd); tables (B, NB);
+        q: (B, C, H, hd); pool_k/pool_v: (N, KV, bs, hd); tables (B, NB);
         q_start/kv_len: (B,). Returns (B, C, H, hd) in q.dtype.
         ``kv_head_map`` (per-q-head pool KV-head index) overrides the
         contiguous-GQA repeat — used inside shard_map when q heads are

@@ -23,12 +23,11 @@ Resolution order for the backend (see :func:`backend_for`):
   3. platform autodetect: ``pallas`` when compiled Pallas is available
      (TPU) *and* the combination is registered, else ``reference``.
 
-Step 3 also applies as a graceful fallback when a config forces
-``pallas`` for a combination that has no kernel (the mode wins over the
-backend — approximation semantics are never silently changed, execution
-engine may be). :func:`resolve` itself is strict: an unregistered
-combination raises ``NotImplementedError``; unknown names raise
-``ValueError``.
+Only step 3 chooses: a backend demanded by steps 1 or 2 is used as
+demanded, so a combination with no kernel raises instead of silently
+running (and being measured as) the reference engine. :func:`resolve`
+is strict: an unregistered combination raises ``NotImplementedError``;
+unknown names raise ``ValueError``.
 """
 from __future__ import annotations
 
@@ -117,22 +116,19 @@ def backend_for(cfg, op: str, mode: str,
     """Resolve the backend for one (op, mode) call site.
 
     ``cfg`` is an ``ArchConfig`` (or None); its ``ops_backend`` field is
-    the per-model selection knob. Config-driven and autodetected
-    choices fall back to ``reference`` when the chosen backend has no
-    implementation for this combination; an *explicit* ``backend``
-    argument is strict — it is returned as-is so :func:`resolve` raises
-    instead of silently measuring/serving a different engine than the
-    caller demanded.
+    the per-model selection knob. A backend demanded by the ``backend``
+    argument or by the config raises ``NotImplementedError`` when this
+    combination has no implementation for it — a run that forced
+    ``pallas`` never serves (or is measured on) a different engine than
+    it asked for. Only ``auto`` falls back to ``reference`` for a
+    combination without a kernel.
     """
-    if backend is not None and backend != "auto":
-        _check_names(op, mode, backend)
-        return backend
     b = backend
     if b is None:
         b = getattr(cfg, "ops_backend", "auto") if cfg is not None else "auto"
-    if b == "auto":
-        b = default_backend()
+    if b != "auto":
+        resolve(op, mode, b)
+        return b
+    b = default_backend()
     _check_names(op, mode, b)
-    if not is_registered(op, mode, b):
-        b = "reference"
-    return b
+    return b if is_registered(op, mode, b) else "reference"

@@ -71,10 +71,14 @@ Array = jax.Array
 
 # Logical axes of the page pools (see sharding/rules.py: "pages" is
 # replicated by default; kv_heads shard over the model axis so each
-# device holds its heads' slice of every page).
+# device holds its heads' slice of every page). Pages are head-major,
+# (layers, pages, kv_heads, block_size, head_dim): one (page, head) is a
+# contiguous (block_size, head_dim) tile, the block the paged kernel
+# DMAs — a TPU block's last two dims must be the array's own or
+# (8, 128)-aligned, so the head axis cannot sit between them.
 PAGED_KV_AXES = {
-    "k": ("layers", "pages", None, "kv_heads", "head_dim"),
-    "v": ("layers", "pages", None, "kv_heads", "head_dim"),
+    "k": ("layers", "pages", "kv_heads", None, "head_dim"),
+    "v": ("layers", "pages", "kv_heads", None, "head_dim"),
 }
 
 
@@ -103,8 +107,8 @@ class PagedKVCache:
         # 0 and gets zero-byte pools with all host bookkeeping intact.
         self.kv_layers = cfg.n_layers if kv_layers is None else kv_layers
         dt = dtype or kv_store_dtype(cfg)
-        shape = (self.kv_layers, num_blocks, block_size,
-                 cfg.n_kv_heads, cfg.head_dim)
+        shape = (self.kv_layers, num_blocks, cfg.n_kv_heads,
+                 block_size, cfg.head_dim)
         self.pools: Dict[str, Array] = {"k": jnp.zeros(shape, dt),
                                         "v": jnp.zeros(shape, dt)}
         # LIFO free list; page 0 reserved as the null page.
@@ -482,10 +486,12 @@ def write_tokens(pool: Array, kv: Array, block_ids: Array,
                  offsets: Array) -> Array:
     """Scatter token KV rows into one layer's page pool.
 
-    pool: (N, bs, KV, hd); kv: (B, C, KV, hd); block_ids/offsets: (B, C)
+    pool: (N, KV, bs, hd); kv: (B, C, KV, hd); block_ids/offsets: (B, C)
     int32 page id / in-page slot per token (masked tokens aim at page 0).
+    The two index arrays are split by the head slice, so the indexed
+    view is (B, C, KV, hd) — the layout ``kv`` already has.
     """
-    return pool.at[block_ids, offsets].set(kv.astype(pool.dtype))
+    return pool.at[block_ids, :, offsets].set(kv.astype(pool.dtype))
 
 
 def slots_for_positions(positions: Array, block_size: int,
@@ -521,11 +527,12 @@ def copy_pages(pools: Dict[str, Array], src: Array,
 def gather_kv(pool: Array, table: Array) -> Array:
     """Reference path: gather one layer's pages to a contiguous cache.
 
-    pool: (N, bs, KV, hd); table: (B, NB) -> (B, NB*bs, KV, hd). Used by
+    pool: (N, KV, bs, hd); table: (B, NB) -> (B, NB*bs, KV, hd). Used by
     the XLA fallback backend and by paged-vs-dense equivalence tests; the
     Pallas backend never materializes this.
     """
-    n, bs, kvh, hd = pool.shape
+    n, kvh, bs, hd = pool.shape
     b, nb = table.shape
-    pages = jnp.take(pool, table.reshape(-1), axis=0)
+    pages = jnp.take(pool, table.reshape(-1), axis=0)   # (B*NB, KV, bs, hd)
+    pages = pages.reshape(b, nb, kvh, bs, hd).transpose(0, 1, 3, 2, 4)
     return pages.reshape(b, nb * bs, kvh, hd)

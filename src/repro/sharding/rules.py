@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
 import math
 from typing import Optional, Sequence, Tuple
 
@@ -18,16 +19,11 @@ import jax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-# jax >= 0.6 exposes jax.shard_map (replication-check kwarg: check_vma);
-# 0.4/0.5 ship it under jax.experimental with check_rep. Modules that
-# need per-shard code (moe dispatch, paged attention TP) import the shim
-# from here so the version split lives in one place.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-    SHARD_MAP_NOCHECK = {"check_vma": False}
-else:  # pragma: no cover - exercised on older jax only
-    from jax.experimental.shard_map import shard_map
-    SHARD_MAP_NOCHECK = {"check_rep": False}
+# Modules that need per-shard code (moe dispatch, paged attention TP)
+# import these from here: per-shard bodies that read axis_index or mix
+# replicated and sharded operands skip the replication check.
+shard_map = jax.shard_map
+SHARD_MAP_NOCHECK = {"check_vma": False}
 
 # logical axis -> preferred mesh axes (joined). Tuples shard over the
 # product of the listed mesh axes (those present in the mesh).
@@ -152,6 +148,36 @@ def use_rules(rules: Optional[Rules]):
 
 def active_rules() -> Optional[Rules]:
     return _ACTIVE.get()
+
+
+def unpartitioned(fn):
+    """Run a Pallas kernel entry point whole on every device of the
+    active rules' mesh.
+
+    GSPMD cannot partition a Mosaic custom call, so under a mesh a
+    kernel call must sit inside ``shard_map``. This wrapper puts it
+    there with every operand replicated: each device computes the whole
+    op, which is what tensor parallelism already holds for the norm and
+    softmax inputs (their ``embed`` dim is never sharded), and what
+    per-call PTF calibration needs to see the same rows as on one
+    device. Without active rules, or inside a shard_map body (e.g. the
+    head-split paged attention), ``fn`` is called as is.
+    """
+    @functools.wraps(fn)
+    def wrapped(*args, **kw):
+        rules = _ACTIVE.get()
+        if rules is None or jax.sharding.get_abstract_mesh().manual_axes:
+            return fn(*args, **kw)
+        # array-valued keywords (masks, head maps) enter as operands;
+        # the rest (modes, block sizes) stay static.
+        dyn = {k: v for k, v in kw.items()
+               if any(isinstance(leaf, jax.Array)
+                      for leaf in jax.tree.leaves(v))}
+        static = {k: v for k, v in kw.items() if k not in dyn}
+        return shard_map(lambda a, d: fn(*a, **d, **static),
+                         mesh=rules.mesh, in_specs=P(), out_specs=P(),
+                         **SHARD_MAP_NOCHECK)(args, dyn)
+    return wrapped
 
 
 def constrain(x: jax.Array, *logical_axes: Optional[str]) -> jax.Array:

@@ -10,9 +10,10 @@ def test_scan_flops_multiplied_and_collectives_counted():
     out = run_with_devices("""
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
+from repro.launch.mesh import make_mesh
 from repro.roofline.hlo_cost import analyze_text
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 def body(carry, _):
     x, w = carry
     return (jax.nn.relu(jnp.dot(x, w)), w), None

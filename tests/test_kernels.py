@@ -132,14 +132,16 @@ def test_flash_sole_ragged_single_block_bit_exact(rng):
 
 
 def _page_pool(rng, n, bs, kv, hd):
-    kp = jnp.asarray(rng.normal(0, 1, (n, bs, kv, hd)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(0, 1, (n, bs, kv, hd)).astype(np.float32))
+    """Head-major (n, KV, bs, hd) K and V pools."""
+    kp = jnp.asarray(rng.normal(0, 1, (n, kv, bs, hd)).astype(np.float32))
+    vp = jnp.asarray(rng.normal(0, 1, (n, kv, bs, hd)).astype(np.float32))
     return kp, vp
 
 
 def _gather(pool, table, t):
     """Host-side oracle gather: pages -> contiguous (t, KV, hd)."""
-    pages = np.concatenate([np.asarray(pool)[p] for p in table], 0)
+    pages = np.concatenate(
+        [np.asarray(pool)[p].transpose(1, 0, 2) for p in table], 0)
     return pages[:t]
 
 

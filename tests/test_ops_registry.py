@@ -53,13 +53,17 @@ def test_unknown_names_raise_value_error():
 
 
 def test_backend_for_falls_back_to_reference():
-    """A config forcing pallas for a combination with no kernel keeps
-    the mode and falls back to the reference engine."""
-    cfg = dataclasses.replace(get_config("qwen2_0_5b").smoke(),
-                              ops_backend="pallas")
+    """Only ``auto`` falls back: it keeps the mode and picks the
+    reference engine for a combination with no kernel. A config that
+    forces pallas for such a combination raises instead."""
+    auto = get_config("qwen2_0_5b").smoke()
+    assert ops.backend_for(auto, "softmax", "ibert") == "reference"
+    assert ops.backend_for(auto, "layernorm", "exact") == "reference"
+    cfg = dataclasses.replace(auto, ops_backend="pallas")
     assert ops.backend_for(cfg, "softmax", "sole") == "pallas"
-    assert ops.backend_for(cfg, "softmax", "ibert") == "reference"
-    assert ops.backend_for(cfg, "layernorm", "exact") == "reference"
+    for op, mode in (("softmax", "ibert"), ("layernorm", "exact")):
+        with pytest.raises(NotImplementedError, match="no 'pallas' backend"):
+            ops.backend_for(cfg, op, mode)
     # explicit argument beats the config
     assert ops.backend_for(cfg, "softmax", "sole", "reference") == "reference"
 
@@ -67,7 +71,9 @@ def test_backend_for_falls_back_to_reference():
 def test_explicit_backend_is_strict():
     """An explicit backend= demand is never silently downgraded: a
     combination without that engine raises instead."""
-    assert ops.backend_for(None, "softmax", "ibert", "pallas") == "pallas"
+    assert ops.backend_for(None, "softmax", "sole", "pallas") == "pallas"
+    with pytest.raises(NotImplementedError, match="no 'pallas' backend"):
+        ops.backend_for(None, "softmax", "ibert", "pallas")
     with pytest.raises(NotImplementedError, match="no 'pallas' backend"):
         ops.softmax_fn("ibert", backend="pallas")
 
@@ -189,8 +195,8 @@ def test_flash_attention_backends_agree_ragged(rng, mode):
 @pytest.mark.parametrize("mode", ["exact", "sole"])
 def test_paged_attention_backends_agree(rng, mode):
     n, bs, kv, hd, h, b, c = 12, 4, 2, 16, 4, 2, 1
-    kp = jnp.asarray(rng.normal(0, 1, (n, bs, kv, hd)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(0, 1, (n, bs, kv, hd)).astype(np.float32))
+    kp = jnp.asarray(rng.normal(0, 1, (n, kv, bs, hd)).astype(np.float32))
+    vp = jnp.asarray(rng.normal(0, 1, (n, kv, bs, hd)).astype(np.float32))
     tables = jnp.asarray(np.array([[3, 1, 6, 2], [5, 2, 7, 9]], np.int32))
     q = jnp.asarray(rng.normal(0, 1, (b, c, h, hd)).astype(np.float32))
     q_start = jnp.asarray([9, 12], jnp.int32)

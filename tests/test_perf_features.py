@@ -89,11 +89,11 @@ def test_chunked_wkv_model_level():
 
 
 def test_fsdp_param_spec():
-    import jax as _jax
     from jax.sharding import PartitionSpec as P
 
+    from repro.launch.mesh import make_mesh
     from repro.sharding.rules import Rules, fsdp_param_spec
-    mesh = _jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     r = Rules.__new__(Rules)
     r.mesh = mesh
     r.table = {}

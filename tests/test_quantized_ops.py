@@ -69,8 +69,9 @@ def test_matmul_cells_resolve_or_raise():
 
 def test_residual_norm_q_cells():
     """The quant-out residual-norm twins cover every norm mode on
-    reference; pallas fuses the SOLE cell only, and the helper falls
-    back to reference for the rest instead of changing the mode."""
+    reference; pallas fuses the SOLE cell only. ``auto`` falls back to
+    reference for the rest instead of changing the mode; a config that
+    forces pallas there raises."""
     for kind in ("layernorm", "rmsnorm"):
         for mode in ops.NORM_MODES:
             assert ops.is_registered(f"residual_{kind}_q", mode,
@@ -80,7 +81,10 @@ def test_residual_norm_q_cells():
     cfg = dataclasses.replace(get_config("qwen2_0_5b").smoke(),
                               ops_backend="pallas")
     assert ops.backend_for(cfg, "residual_layernorm_q", "sole") == "pallas"
-    assert ops.backend_for(cfg, "residual_layernorm_q", "exact") \
+    with pytest.raises(NotImplementedError, match="no 'pallas' backend"):
+        ops.backend_for(cfg, "residual_layernorm_q", "exact")
+    auto = dataclasses.replace(cfg, ops_backend="auto")
+    assert ops.backend_for(auto, "residual_layernorm_q", "exact") \
         == "reference"
 
 
@@ -269,7 +273,7 @@ import dataclasses
 import numpy as np
 import jax
 from repro.configs.base import QuantConfig, get_config
-from repro.launch.mesh import make_rules
+from repro.launch.mesh import make_mesh, make_rules
 from repro.models import api
 from repro.serve.engine import PagedEngine, Request
 
@@ -289,7 +293,7 @@ def outs(rules, pa):
     return eng.generate(reqs)
 
 single = outs(None, None)
-rules = make_rules(jax.make_mesh((1, 2), ("data", "model")))
+rules = make_rules(make_mesh((1, 2), ("data", "model")))
 sharded = outs(rules, axes)
 assert sharded == single, (single, sharded)
 print("W8A8-MESH-OK")

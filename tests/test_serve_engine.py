@@ -91,7 +91,7 @@ def test_null_page_garbage_invariance(exact_lm, rng):
                               backend=backend)
             if garbage:
                 g = np.random.default_rng(99).normal(0, 50.0, (
-                    cfg.n_layers, eng.cache.block_size, cfg.n_kv_heads,
+                    cfg.n_layers, cfg.n_kv_heads, eng.cache.block_size,
                     cfg.head_dim))
                 for name, pool in eng.cache.pools.items():
                     eng.cache.pools[name] = pool.at[:, 0].set(
@@ -273,9 +273,9 @@ def test_paged_decode_inputs_spec(small_lm):
     shape = ShapeConfig("t", seq_len=64, global_batch=4, kind="decode")
     state, axes, token, pos, refs = api.paged_decode_inputs(
         cfg, shape, block_size=16)
-    assert state["k"].shape == (cfg.n_layers, 4 * 4 + 1, 16,
-                                cfg.n_kv_heads, cfg.head_dim)
-    assert axes["k"][1] == "pages"
+    assert state["k"].shape == (cfg.n_layers, 4 * 4 + 1, cfg.n_kv_heads,
+                                16, cfg.head_dim)
+    assert axes["k"][1] == "pages" and axes["k"][2] == "kv_heads"
     assert token.shape == (4,) and pos.shape == (4,)
     assert refs["tables"].shape == (4, 4)
     assert "slots" not in state       # dense carries no recurrent slots

@@ -96,7 +96,7 @@ import dataclasses
 import numpy as np
 import jax, jax.numpy as jnp
 from repro.configs.base import get_config
-from repro.launch.mesh import make_rules
+from repro.launch.mesh import make_mesh, make_rules
 from repro.models import api
 from repro.serve.engine import PagedEngine, Request
 from repro.serve.loop import AsyncEngine
@@ -126,7 +126,7 @@ def engine(rules=None, ax=None, **kw):
     return PagedEngine(cfg, params, **base)
 
 
-mesh = jax.make_mesh(SHAPE, ("data", "model"))
+mesh = make_mesh(SHAPE, ("data", "model"))
 rules = make_rules(mesh)
 """
 

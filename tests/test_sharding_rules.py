@@ -1,10 +1,10 @@
 """Logical-axis rules: divisibility fallback + ZeRO-1 spec (no mesh exec)."""
 import math
 
-import jax
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.sharding.rules import (DEFAULT_RULES, Rules, fsdp_param_spec,
                                   zero1_spec)
 from tests._hypothesis_compat import given, settings, st
@@ -13,7 +13,7 @@ from tests._hypothesis_compat import given, settings, st
 def _fake_rules(axis_sizes):
     """Rules over a 1-device mesh with injected production axis sizes
     (spec logic only touches axis_sizes)."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     r = Rules.__new__(Rules)
     r.mesh = mesh
     r.table = dict(DEFAULT_RULES)

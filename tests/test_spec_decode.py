@@ -428,7 +428,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config
-from repro.launch.mesh import make_rules
+from repro.launch.mesh import make_mesh, make_rules
 from repro.models import api
 from repro.serve.engine import PagedEngine, Request
 from repro.serve.spec import DraftModelDrafter, SpecConfig
@@ -438,7 +438,7 @@ cfg = get_config("qwen2_0_5b").smoke()
 params, axes = api.init_params(jax.random.PRNGKey(0), cfg)
 cfg = dataclasses.replace(cfg, softmax_mode="exact", norm_mode="exact",
                           logit_int8=False)
-mesh = jax.make_mesh(SHAPE, ("data", "model"))
+mesh = make_mesh(SHAPE, ("data", "model"))
 rules = make_rules(mesh)
 rng = np.random.default_rng(41)
 reqs = ([Request(prompt=rng.integers(0, cfg.vocab_size, 16)

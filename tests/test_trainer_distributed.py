@@ -9,7 +9,7 @@ pytestmark = pytest.mark.slow
 
 def test_train_loss_decreases_and_recovers_from_failure():
     out = run_with_devices("""
-import jax
+import tempfile, jax
 from repro.configs.base import get_config, ShapeConfig
 from repro.launch.mesh import smoke_mesh, make_rules
 from repro.train.trainer import Trainer
@@ -19,13 +19,13 @@ cfg = get_config("qwen2_0_5b").smoke()
 shape = ShapeConfig("smoke", seq_len=64, global_batch=8, kind="train")
 rules = make_rules(smoke_mesh(4, 2))
 tr = Trainer(cfg, shape, OptConfig(lr=1e-2, warmup_steps=5, total_steps=60),
-             rules, ckpt_dir="/tmp/ckpt_t1", ckpt_every=10)
+             rules, ckpt_dir=tempfile.mkdtemp(), ckpt_every=10)
 out = tr.run(25)
 losses = [m["loss"] for m in out["metrics"]]
 assert losses[-1] < losses[0] - 0.2, f"no learning: {losses[0]} -> {losses[-1]}"
 
 tr2 = Trainer(cfg, shape, OptConfig(lr=1e-2, warmup_steps=5, total_steps=60),
-              rules, ckpt_dir="/tmp/ckpt_t2", ckpt_every=5)
+              rules, ckpt_dir=tempfile.mkdtemp(), ckpt_every=5)
 out2 = tr2.run(12, fail_at=8)
 assert len(out2["metrics"]) >= 12, "failure recovery did not complete steps"
 print("PASS")
@@ -37,7 +37,7 @@ def test_resume_bitwise_equals_uninterrupted():
     """Checkpoint at step 5, keep training to 10; separately restore at 5
     and train 5 more — identical params (deterministic data pipeline)."""
     out = run_with_devices("""
-import numpy as np, jax
+import tempfile, numpy as np, jax
 from repro.configs.base import get_config, ShapeConfig
 from repro.launch.mesh import smoke_mesh, make_rules
 from repro.train.trainer import Trainer
@@ -46,18 +46,19 @@ from repro.train.optimizer import OptConfig
 cfg = get_config("qwen2_0_5b").smoke()
 shape = ShapeConfig("smoke", seq_len=32, global_batch=8, kind="train")
 rules = make_rules(smoke_mesh(4, 2))
+ckpt = tempfile.mkdtemp()
 opt = OptConfig(lr=1e-2, warmup_steps=2, total_steps=20)
 
-a = Trainer(cfg, shape, opt, rules, ckpt_dir="/tmp/ckpt_resume", ckpt_every=5)
+a = Trainer(cfg, shape, opt, rules, ckpt_dir=ckpt, ckpt_every=5)
 a.run(10)
 ref = jax.tree.map(np.asarray, a.params)
 
-b = Trainer(cfg, shape, opt, rules, ckpt_dir="/tmp/ckpt_resume")
+b = Trainer(cfg, shape, opt, rules, ckpt_dir=ckpt)
 b.restore()
 assert b.step == 10
-b2 = Trainer(cfg, shape, opt, rules, ckpt_dir="/tmp/ckpt_resume")
+b2 = Trainer(cfg, shape, opt, rules, ckpt_dir=ckpt)
 import repro.train.checkpoint as ck
-step, tree = ck.restore("/tmp/ckpt_resume",
+step, tree = ck.restore(ckpt,
                         {"params": b2.params, "opt": b2.opt_state}, step=5)
 b2.params, b2.opt_state, b2.step = tree["params"], tree["opt"], 5
 b2.saver = None
@@ -73,9 +74,9 @@ print("PASS")
 def test_elastic_remesh_restore():
     """Save on (4 data, 2 model); restore onto (2 data, 4 model)."""
     out = run_with_devices("""
-import numpy as np, jax
+import tempfile, numpy as np, jax
 from repro.configs.base import get_config, ShapeConfig
-from repro.launch.mesh import smoke_mesh, make_rules
+from repro.launch.mesh import make_mesh, smoke_mesh, make_rules
 from repro.train.trainer import Trainer
 from repro.train.optimizer import OptConfig
 from repro.train.elastic import reshard_checkpoint
@@ -85,15 +86,15 @@ cfg = get_config("qwen2_0_5b").smoke()
 shape = ShapeConfig("smoke", seq_len=32, global_batch=8, kind="train")
 r1 = make_rules(smoke_mesh(4, 2))
 tr = Trainer(cfg, shape, OptConfig(lr=1e-2, total_steps=10), r1,
-             ckpt_dir="/tmp/ckpt_elastic", ckpt_every=4)
+             ckpt_dir=(ckpt := tempfile.mkdtemp()), ckpt_every=4)
 tr.run(4)
 ref = jax.tree.map(np.asarray, tr.params)
 
-r2 = make_rules(jax.make_mesh((2, 4), ("data", "model")))
+r2 = make_rules(make_mesh((2, 4), ("data", "model")))
 with r2.mesh:
     params_t, axes = api.init_params(jax.random.PRNGKey(0), cfg)
 opt_axes = {"step": (), "mu": axes, "nu": axes}
-step, tree = reshard_checkpoint("/tmp/ckpt_elastic",
+step, tree = reshard_checkpoint(ckpt,
                                 {"params": params_t, "opt": tr.opt_state},
                                 r2, {"params": axes, "opt": opt_axes},
                                 )
@@ -117,6 +118,7 @@ import numpy as np, jax, jax.numpy as jnp, dataclasses
 from repro.configs.base import get_config
 from repro.models.moe import apply_moe_ffn, init_moe_ffn
 from repro.models.layers import split_params
+from repro.launch.mesh import make_mesh
 from repro.sharding import rules as R
 
 cfg = dataclasses.replace(get_config("dbrx_132b").smoke(),
@@ -126,7 +128,7 @@ x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model)) * 0.3
 
 ref, aux_ref = apply_moe_ffn(p, x, cfg, "train")   # no rules -> local path
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))   # experts=4 -> EP over data
+mesh = make_mesh((4, 2), ("data", "model"))   # experts=4 -> EP over data
 rules = R.Rules(mesh)
 with mesh, R.use_rules(rules):
     out, aux = jax.jit(lambda p, x: apply_moe_ffn(p, x, cfg, "train"))(p, x)
