@@ -247,7 +247,8 @@ def _paged_forward(params, tokens, positions, n_valid, kv_len, tables,
                  and isinstance(lay.get("mlp"), dict)
                  and L.is_qtensor(lay["mlp"].get("up")))
     ffn_apply = ffn_apply or (lambda p, x, c, ph: L.apply_mlp(x, p, c))
-    x = L.embed_tokens(params["embed"], tokens, cfg)
+    with jax.named_scope("embed"):
+        x = L.embed_tokens(params["embed"], tokens, cfg)
     q_start = positions[:, 0]
     # Pin the pool layout (kv_heads over model, pages host-global) so
     # donated jit round trips and the scatter/attend pair below keep one
@@ -263,33 +264,46 @@ def _paged_forward(params, tokens, positions, n_valid, kv_len, tables,
     leaves = [jax.tree.map(lambda a: a[i], params["layers"])
               for i in range(cfg.n_layers)]
     pending = None                      # deferred MLP residual
+    # Each sublayer runs under a stable jax.named_scope (the op_name
+    # path of its HLO ops): embed, norm, qkv, kv_write, paged_attention,
+    # attn_out, mlp, lm_head, and sample in decode_horizon_paged. A
+    # trace reduction attributes device time by these names.
     for i, lp in enumerate(leaves):
-        if pending is None:
-            h = L.apply_norm(x, lp["ln1"], cfg, "serve")
-        else:
-            x, h = L.apply_residual_norm(x, pending, lp["ln1"], cfg, "serve",
-                                         quant_out=qact)
-        q, k, v = L._project_qkv(lp["attn"], h, cfg)
-        if cfg.pos_kind == "rope":
-            q = L.apply_rope(q, positions, cfg)
-            k = L.apply_rope(k, positions, cfg)
-        pk = pk.at[i].set(write_tokens(pk[i], L.kv_quant(k, cfg),
-                                       block_ids, offsets))
-        pv = pv.at[i].set(write_tokens(pv[i], L.kv_quant(v, cfg),
-                                       block_ids, offsets))
-        ctx = L.paged_attend(q, pk[i], pv[i], tables, q_start, kv_len,
-                             cfg, causal=causal, backend=backend)
-        attn_out = L._wo_proj(ctx, lp["attn"], cfg)
-        x, h = L.apply_residual_norm(x, attn_out, lp["ln2"], cfg, "serve",
-                                     quant_out=quant_ffn)
+        with jax.named_scope("norm"):
+            if pending is None:
+                h = L.apply_norm(x, lp["ln1"], cfg, "serve")
+            else:
+                x, h = L.apply_residual_norm(x, pending, lp["ln1"], cfg,
+                                             "serve", quant_out=qact)
+        with jax.named_scope("qkv"):
+            q, k, v = L._project_qkv(lp["attn"], h, cfg)
+            if cfg.pos_kind == "rope":
+                q = L.apply_rope(q, positions, cfg)
+                k = L.apply_rope(k, positions, cfg)
+        with jax.named_scope("kv_write"):
+            pk = pk.at[i].set(write_tokens(pk[i], L.kv_quant(k, cfg),
+                                           block_ids, offsets))
+            pv = pv.at[i].set(write_tokens(pv[i], L.kv_quant(v, cfg),
+                                           block_ids, offsets))
+        with jax.named_scope("paged_attention"):
+            ctx = L.paged_attend(q, pk[i], pv[i], tables, q_start, kv_len,
+                                 cfg, causal=causal, backend=backend)
+        with jax.named_scope("attn_out"):
+            attn_out = L._wo_proj(ctx, lp["attn"], cfg)
+        with jax.named_scope("norm"):
+            x, h = L.apply_residual_norm(x, attn_out, lp["ln2"], cfg,
+                                         "serve", quant_out=quant_ffn)
         x = constrain(x, "batch", "seq", "embed")
-        pending = ffn_apply(lp["mlp"], h, cfg, "serve")
-    if pending is None:
-        x = L.apply_norm(x, params["final_norm"], cfg, "serve")
-    else:
-        _, x = L.apply_residual_norm(x, pending, params["final_norm"],
-                                     cfg, "serve", quant_out=qact)
-    logits = L.lm_logits(params["embed"], x, cfg)
+        with jax.named_scope("mlp"):
+            pending = ffn_apply(lp["mlp"], h, cfg, "serve")
+    with jax.named_scope("norm"):
+        if pending is None:
+            x = L.apply_norm(x, params["final_norm"], cfg, "serve")
+        else:
+            _, x = L.apply_residual_norm(x, pending, params["final_norm"],
+                                         cfg, "serve", quant_out=qact)
+    with jax.named_scope("lm_head"):
+        logits = L.lm_logits(params["embed"], x, cfg)
     return logits, {"k": pk, "v": pv}
 
 
@@ -376,11 +390,12 @@ def decode_horizon_paged(params, pools, token: Array, pos: Array,
         logits, pools = decode_step_paged(params, pools, tok, p, tables,
                                           cfg, backend=backend,
                                           ffn_apply=ffn_apply)
-        nxt = sample_tokens(logits, temperature, top_k, seed,
-                            counter + i, cfg.vocab_size,
-                            use_top_k=use_top_k, stochastic=stochastic)
-        done = (eos_hits(nxt, eos_ids) if use_eos
-                else jnp.zeros(nxt.shape, jnp.bool_))
+        with jax.named_scope("sample"):
+            nxt = sample_tokens(logits, temperature, top_k, seed,
+                                counter + i, cfg.vocab_size,
+                                use_top_k=use_top_k, stochastic=stochastic)
+            done = (eos_hits(nxt, eos_ids) if use_eos
+                    else jnp.zeros(nxt.shape, jnp.bool_))
         return (pools, nxt, p + 1), (nxt, done)
 
     (pools, _, _), (toks, done) = jax.lax.scan(
@@ -429,9 +444,11 @@ def verify_paged(params, pools, tokens: Array, q_start: Array,
     flat = logits.reshape(b * c, logits.shape[-1])
     ctr = (counter[:, None] + jnp.arange(c)[None]).reshape(-1)
     rep = lambda a: jnp.repeat(a, c)     # (B,) lane params -> (B*C,)
-    pinned = sample_tokens(flat, rep(temperature), rep(top_k), rep(seed),
-                           ctr, cfg.vocab_size, use_top_k=use_top_k,
-                           stochastic=stochastic).reshape(b, c)
-    done = (eos_hits(pinned, eos_ids[:, None, :]) if use_eos
-            else jnp.zeros(pinned.shape, jnp.bool_))
+    with jax.named_scope("sample"):
+        pinned = sample_tokens(flat, rep(temperature), rep(top_k),
+                               rep(seed), ctr, cfg.vocab_size,
+                               use_top_k=use_top_k,
+                               stochastic=stochastic).reshape(b, c)
+        done = (eos_hits(pinned, eos_ids[:, None, :]) if use_eos
+                else jnp.zeros(pinned.shape, jnp.bool_))
     return pinned, done, pools

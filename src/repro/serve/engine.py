@@ -39,6 +39,9 @@ from repro.serve.state import StateCheckpointCache, StateSlotPool
 from repro.sharding import rules as R
 
 Array = jax.Array
+# Host spans on the profiler's clock: recorded only while a profiler is
+# collecting, one inactive TraceMe each otherwise.
+_span = jax.profiler.TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -404,23 +407,28 @@ class PagedEngine:
         start = seq.prefilled
         replay = seq.replay_tokens
         real = min(c, len(replay) - start)
-        copies = self.sched.ensure_tokens(seq, start, start + real)
-        if copies is None:
-            return                       # seq itself was preempted
-        self._apply_copies(copies)
-        self._init_state(seq)
-        chunk = np.zeros((1, c), np.int32)
-        chunk[0, :real] = replay[start:start + real]
-        refs = self._refs([seq])
-        logits, state = self._prefill(
-            self.params, self._state(), jnp.asarray(chunk),
-            jnp.asarray(np.array([start], np.int32)),
-            jnp.asarray(np.array([real], np.int32)),
-            refs)
-        self._put_state(state)
-        seq.prefilled = start + real
-        self._maybe_checkpoint(seq, start + real)
-        if not seq.in_prefill:
+        with _span("engine.prefill", seq=seq.seq_id, start=start,
+                   rows=real):
+            with _span("engine.stage"):
+                copies = self.sched.ensure_tokens(seq, start, start + real)
+                if copies is None:
+                    return               # seq itself was preempted
+                self._apply_copies(copies)
+                self._init_state(seq)
+                chunk = np.zeros((1, c), np.int32)
+                chunk[0, :real] = replay[start:start + real]
+                refs = self._refs([seq])
+            with _span("engine.dispatch"):
+                logits, state = self._prefill(
+                    self.params, self._state(), jnp.asarray(chunk),
+                    jnp.asarray(np.array([start], np.int32)),
+                    jnp.asarray(np.array([real], np.int32)),
+                    refs)
+            self._put_state(state)
+            seq.prefilled = start + real
+            self._maybe_checkpoint(seq, start + real)
+            if seq.in_prefill:
+                return
             if self.state_spec.has_pages:
                 self.cache.register_prompt(seq.seq_id, seq.prompt,
                                            seq.prefix_keys)
@@ -431,12 +439,15 @@ class PagedEngine:
                 # whole-array d2h, then host indexing: indexing the
                 # device array first would transfer the index scalars
                 # h2d, tripping the decode-loop transfer guard.
-                tok = seq.sampler(np.asarray(logits)[0, real - 1])
-                # the very first token can already be a finish event
-                # (eos, or a single-token stop sequence): the sequence
-                # must never enter a decode batch.
-                _, seq.finish_reason = apply_finish(seq.sampler, seq.out,
-                                                    [tok])
+                with _span("engine.sync"):
+                    row = np.asarray(logits)[0, real - 1]
+                with _span("engine.finish"):
+                    tok = seq.sampler(row)
+                    # the very first token can already be a finish event
+                    # (eos, or a single-token stop sequence): the
+                    # sequence must never enter a decode batch.
+                    _, seq.finish_reason = apply_finish(seq.sampler,
+                                                        seq.out, [tok])
 
     def _decode_step(self) -> None:
         batch = self.sched.decode_batch(self.decode_batch)
@@ -446,91 +457,105 @@ class PagedEngine:
         if h == 0:
             return
         h = 1 << (h.bit_length() - 1)
-        lanes: List[Sequence] = []
-        for seq in batch:
-            if seq not in self.sched.running:
-                continue                 # preempted by an earlier lane
-            pos = seq.prompt_len + len(seq.out) - 1
-            # pre-extend the page table for the whole horizon: every
-            # page the in-jit scan will write exists, and is private
-            # (COW copies surfaced here), before dispatch.
-            copies = self.sched.ensure_tokens(seq, pos, pos + h)
-            if copies is None:
-                continue
-            self._apply_copies(copies)
-            lanes.append(seq)
-        # victim policy invariant: ensure_tokens preempts youngest-first
-        # and stops at the requesting seq, so a later lane's growth can
-        # only evict lanes *after* it in running order — never one
-        # already collected above. Device writes rely on this.
-        assert all(s in self.sched.running for s in lanes)
-        if not lanes:
-            return
-        d = self.decode_batch
-        token = np.zeros((d,), np.int32)
-        pos = np.zeros((d,), np.int32)
-        temp = np.zeros((d,), np.float32)     # null lanes decode greedily
-        topk = np.zeros((d,), np.int32)
-        seed = np.zeros((d,), np.uint32)
-        ctr = np.zeros((d,), np.int32)
-        seqs: List[Optional[Sequence]] = [None] * d
-        for i, seq in enumerate(lanes):
-            token[i] = seq.out[-1]
-            pos[i] = seq.prompt_len + len(seq.out) - 1
-            s = seq.sampler
-            temp[i], topk[i], seed[i] = s.temperature, s.top_k, s.seed
-            # token n draws with counter n: the host sampler spent
-            # counter 0 on the prefill-logits token, so the device
-            # stream continues exactly where it left off.
-            ctr[i] = len(seq.out)
-            seqs[i] = seq
-        refs = self._refs(seqs)
-        # static sampling fast paths: skipping the top-k rank sorts /
-        # Gumbel rows / eos membership tests is an exact identity for
-        # lanes that don't use them, so flags from the live batch never
-        # change any draw. The eos table width is pow2-rounded so lane
-        # mixes compile a handful of shapes, not one per mix.
-        use_top_k = any(s.sampler.top_k > 0 for s in lanes)
-        stochastic = any(s.sampler.temperature > 0 for s in lanes)
-        widest = max(len(s.sampler.eos_ids) for s in lanes)
-        use_eos = widest > 0
-        eos = np.full((d, 1), -1, np.int32)
-        if use_eos:
-            width = 1 << (widest - 1).bit_length() if widest > 1 else 1
-            eos = np.full((d, width), -1, np.int32)
-            eos[:len(lanes)] = eos_table([s.sampler for s in lanes], width)
-        toks, done, state = self._decode_h(
-            self.params, self._state(), jnp.asarray(token),
-            jnp.asarray(pos), refs, jnp.asarray(temp), jnp.asarray(topk),
-            jnp.asarray(seed), jnp.asarray(ctr), jnp.asarray(eos), h,
-            use_top_k, stochastic, use_eos)
-        self._put_state(state)
-        rows = np.asarray(toks)
-        done_rows = np.asarray(done)
-        for i, seq in enumerate(lanes):
-            # post-truncation: cut the lane at its first finish event —
-            # the device-computed eos mask, or a host-matched stop
-            # sequence (which may span the horizon boundary). Draws
-            # after the cut never entered the stream, so the host
-            # counter advances by the kept count only.
-            kept, reason = apply_finish(seq.sampler, seq.out, rows[i],
-                                        eos_row=done_rows[i])
-            seq.sampler.skip(kept)       # host stream stays aligned
-            # the horizon wrote the fed tokens' KV at pos[i]..pos[i]+h-1,
-            # but only the first `kept` positions hold tokens the
-            # sequence keeps: prefilled tracks *valid* written KV.
-            seq.prefilled = int(pos[i]) + kept
-            self.decode_tokens += kept
-            self.truncated_tokens += h - kept
-            if reason is not None:
-                seq.finish_reason = reason
-                # reclaim the pre-extended horizon tail the lane will
-                # never write: pages return to the pool mid-step, so
-                # they fund this step's reap/admit instead of idling
-                # until the sequence is released.
-                self.reclaimed_pages += self.cache.truncate(
-                    seq.seq_id, int(pos[i]) + kept)
-        self.decode_dispatches += 1
+        with _span("engine.decode", h=h) as span:
+            with _span("engine.stage"):
+                lanes: List[Sequence] = []
+                for seq in batch:
+                    if seq not in self.sched.running:
+                        continue         # preempted by an earlier lane
+                    pos = seq.prompt_len + len(seq.out) - 1
+                    # pre-extend the page table for the whole horizon:
+                    # every page the in-jit scan will write exists, and
+                    # is private (COW copies surfaced here), before
+                    # dispatch.
+                    copies = self.sched.ensure_tokens(seq, pos, pos + h)
+                    if copies is None:
+                        continue
+                    self._apply_copies(copies)
+                    lanes.append(seq)
+                # victim policy invariant: ensure_tokens preempts
+                # youngest-first and stops at the requesting seq, so a
+                # later lane's growth can only evict lanes *after* it in
+                # running order — never one already collected above.
+                # Device writes rely on this.
+                assert all(s in self.sched.running for s in lanes)
+                if not lanes:
+                    return
+                d = self.decode_batch
+                token = np.zeros((d,), np.int32)
+                pos = np.zeros((d,), np.int32)
+                temp = np.zeros((d,), np.float32)  # null lanes decode greedily
+                topk = np.zeros((d,), np.int32)
+                seed = np.zeros((d,), np.uint32)
+                ctr = np.zeros((d,), np.int32)
+                seqs: List[Optional[Sequence]] = [None] * d
+                for i, seq in enumerate(lanes):
+                    token[i] = seq.out[-1]
+                    pos[i] = seq.prompt_len + len(seq.out) - 1
+                    s = seq.sampler
+                    temp[i], topk[i], seed[i] = s.temperature, s.top_k, s.seed
+                    # token n draws with counter n: the host sampler spent
+                    # counter 0 on the prefill-logits token, so the device
+                    # stream continues exactly where it left off.
+                    ctr[i] = len(seq.out)
+                    seqs[i] = seq
+                refs = self._refs(seqs)
+                # static sampling fast paths: skipping the top-k rank
+                # sorts / Gumbel rows / eos membership tests is an exact
+                # identity for lanes that don't use them, so flags from
+                # the live batch never change any draw. The eos table
+                # width is pow2-rounded so lane mixes compile a handful
+                # of shapes, not one per mix.
+                use_top_k = any(s.sampler.top_k > 0 for s in lanes)
+                stochastic = any(s.sampler.temperature > 0 for s in lanes)
+                widest = max(len(s.sampler.eos_ids) for s in lanes)
+                use_eos = widest > 0
+                eos = np.full((d, 1), -1, np.int32)
+                if use_eos:
+                    width = 1 << (widest - 1).bit_length() if widest > 1 else 1
+                    eos = np.full((d, width), -1, np.int32)
+                    eos[:len(lanes)] = eos_table([s.sampler for s in lanes],
+                                                 width)
+            span.set_metadata(lanes=len(lanes))
+            with _span("engine.dispatch"):
+                toks, done, state = self._decode_h(
+                    self.params, self._state(), jnp.asarray(token),
+                    jnp.asarray(pos), refs, jnp.asarray(temp),
+                    jnp.asarray(topk), jnp.asarray(seed), jnp.asarray(ctr),
+                    jnp.asarray(eos), h, use_top_k, stochastic, use_eos)
+            self._put_state(state)
+            with _span("engine.sync"):
+                rows = np.asarray(toks)
+            done_rows = np.asarray(done)
+            with _span("engine.finish"):
+                for i, seq in enumerate(lanes):
+                    # post-truncation: cut the lane at its first finish
+                    # event — the device-computed eos mask, or a
+                    # host-matched stop sequence (which may span the
+                    # horizon boundary). Draws after the cut never
+                    # entered the stream, so the host counter advances by
+                    # the kept count only.
+                    kept, reason = apply_finish(seq.sampler, seq.out,
+                                                rows[i],
+                                                eos_row=done_rows[i])
+                    seq.sampler.skip(kept)   # host stream stays aligned
+                    # the horizon wrote the fed tokens' KV at
+                    # pos[i]..pos[i]+h-1, but only the first `kept`
+                    # positions hold tokens the sequence keeps: prefilled
+                    # tracks *valid* written KV.
+                    seq.prefilled = int(pos[i]) + kept
+                    self.decode_tokens += kept
+                    self.truncated_tokens += h - kept
+                    if reason is not None:
+                        seq.finish_reason = reason
+                        # reclaim the pre-extended horizon tail the lane
+                        # will never write: pages return to the pool
+                        # mid-step, so they fund this step's reap/admit
+                        # instead of idling until the sequence is
+                        # released.
+                        self.reclaimed_pages += self.cache.truncate(
+                            seq.seq_id, int(pos[i]) + kept)
+            self.decode_dispatches += 1
 
     def _spec_step(self) -> bool:
         """One speculative decode round: draft K tokens per lane, score
@@ -568,94 +593,106 @@ class PagedEngine:
         # pow2 verify width: C = K+1 compiles a handful of shapes
         kmax = 1 << (max(len(d) for d in drafts) - 1).bit_length()
         c = kmax + 1
-        lanes: List[Tuple[Sequence, List[int]]] = []
-        for seq, draft in zip(batch, drafts):
-            if seq not in self.sched.running:
-                continue                 # preempted by an earlier lane
-            pos = seq.prompt_len + len(seq.out) - 1
-            # pre-extend for feed token + all drafts, like the horizon
-            copies = self.sched.ensure_tokens(seq, pos,
-                                              pos + 1 + len(draft))
-            if copies is None:
-                continue
-            self._apply_copies(copies)
-            lanes.append((seq, draft))
-        assert all(s in self.sched.running for s, _ in lanes)
-        if not lanes:
-            return True                  # everything preempted this step
-        d = self.decode_batch
-        tokens = np.zeros((d, c), np.int32)
-        q_start = np.zeros((d,), np.int32)
-        # null lanes mirror the decode scan's self-absorbing null-page
-        # lanes: one fake token written to (and read from) page 0.
-        n_valid = np.ones((d,), np.int32)
-        temp = np.zeros((d,), np.float32)
-        topk = np.zeros((d,), np.int32)
-        seed = np.zeros((d,), np.uint32)
-        ctr = np.zeros((d,), np.int32)
-        seqs: List[Optional[Sequence]] = [None] * d
-        for i, (seq, draft) in enumerate(lanes):
-            row = [seq.out[-1]] + draft
-            tokens[i, :len(row)] = row
-            q_start[i] = seq.prompt_len + len(seq.out) - 1
-            n_valid[i] = len(row)
-            s = seq.sampler
-            temp[i], topk[i], seed[i] = s.temperature, s.top_k, s.seed
-            ctr[i] = len(seq.out)
-            seqs[i] = seq
-        refs = self._refs(seqs)
-        use_top_k = any(s.sampler.top_k > 0 for s, _ in lanes)
-        stochastic = any(s.sampler.temperature > 0 for s, _ in lanes)
-        widest = max(len(s.sampler.eos_ids) for s, _ in lanes)
-        use_eos = widest > 0
-        eos = np.full((d, 1), -1, np.int32)
-        if use_eos:
-            width = 1 << (widest - 1).bit_length() if widest > 1 else 1
-            eos = np.full((d, width), -1, np.int32)
-            eos[:len(lanes)] = eos_table([s.sampler for s, _ in lanes],
-                                         width)
-        pinned, done, state = self._verify(
-            self.params, self._state(), jnp.asarray(tokens),
-            jnp.asarray(q_start), jnp.asarray(n_valid), refs,
-            jnp.asarray(temp), jnp.asarray(topk), jnp.asarray(seed),
-            jnp.asarray(ctr), jnp.asarray(eos), use_top_k, stochastic,
-            use_eos)
-        self._put_state(state)
-        rows = np.asarray(pinned)
-        done_rows = np.asarray(done)
-        for i, (seq, draft) in enumerate(lanes):
-            acc = 0
-            while acc < len(draft) and draft[acc] == rows[i, acc]:
-                acc += 1
-            kept, reason = apply_finish(seq.sampler, seq.out,
-                                        rows[i, :acc + 1],
-                                        eos_row=done_rows[i, :acc + 1])
-            seq.sampler.skip(kept)       # host stream stays aligned
-            pos = int(q_start[i])
-            seq.prefilled = pos + kept   # valid written KV only
-            self.decode_tokens += kept
-            self.truncated_tokens += 1 + len(draft) - kept
-            self.spec_proposed += len(draft)
-            self.spec_accepted += acc
-            self.sched.spec_feedback(seq, len(draft), acc, self.spec)
-            if reason is not None:
-                seq.finish_reason = reason
-            # rejected tails (and finish tails) hand their pre-extended
-            # pages back mid-step via the existing truncate path
-            self.reclaimed_pages += self.cache.truncate(seq.seq_id,
-                                                        pos + kept)
+        with _span("engine.verify", c=c) as span:
+            with _span("engine.stage"):
+                lanes: List[Tuple[Sequence, List[int]]] = []
+                for seq, draft in zip(batch, drafts):
+                    if seq not in self.sched.running:
+                        continue         # preempted by an earlier lane
+                    pos = seq.prompt_len + len(seq.out) - 1
+                    # pre-extend for feed token + all drafts, like the
+                    # horizon
+                    copies = self.sched.ensure_tokens(seq, pos,
+                                                      pos + 1 + len(draft))
+                    if copies is None:
+                        continue
+                    self._apply_copies(copies)
+                    lanes.append((seq, draft))
+                assert all(s in self.sched.running for s, _ in lanes)
+                if not lanes:
+                    return True          # everything preempted this step
+                d = self.decode_batch
+                tokens = np.zeros((d, c), np.int32)
+                q_start = np.zeros((d,), np.int32)
+                # null lanes mirror the decode scan's self-absorbing
+                # null-page lanes: one fake token written to (and read
+                # from) page 0.
+                n_valid = np.ones((d,), np.int32)
+                temp = np.zeros((d,), np.float32)
+                topk = np.zeros((d,), np.int32)
+                seed = np.zeros((d,), np.uint32)
+                ctr = np.zeros((d,), np.int32)
+                seqs: List[Optional[Sequence]] = [None] * d
+                for i, (seq, draft) in enumerate(lanes):
+                    row = [seq.out[-1]] + draft
+                    tokens[i, :len(row)] = row
+                    q_start[i] = seq.prompt_len + len(seq.out) - 1
+                    n_valid[i] = len(row)
+                    s = seq.sampler
+                    temp[i], topk[i], seed[i] = s.temperature, s.top_k, s.seed
+                    ctr[i] = len(seq.out)
+                    seqs[i] = seq
+                refs = self._refs(seqs)
+                use_top_k = any(s.sampler.top_k > 0 for s, _ in lanes)
+                stochastic = any(s.sampler.temperature > 0
+                                 for s, _ in lanes)
+                widest = max(len(s.sampler.eos_ids) for s, _ in lanes)
+                use_eos = widest > 0
+                eos = np.full((d, 1), -1, np.int32)
+                if use_eos:
+                    width = 1 << (widest - 1).bit_length() if widest > 1 else 1
+                    eos = np.full((d, width), -1, np.int32)
+                    eos[:len(lanes)] = eos_table(
+                        [s.sampler for s, _ in lanes], width)
+            span.set_metadata(lanes=len(lanes))
+            with _span("engine.dispatch"):
+                pinned, done, state = self._verify(
+                    self.params, self._state(), jnp.asarray(tokens),
+                    jnp.asarray(q_start), jnp.asarray(n_valid), refs,
+                    jnp.asarray(temp), jnp.asarray(topk), jnp.asarray(seed),
+                    jnp.asarray(ctr), jnp.asarray(eos), use_top_k,
+                    stochastic, use_eos)
+            self._put_state(state)
+            with _span("engine.sync"):
+                rows = np.asarray(pinned)
+            done_rows = np.asarray(done)
+            with _span("engine.finish"):
+                for i, (seq, draft) in enumerate(lanes):
+                    acc = 0
+                    while acc < len(draft) and draft[acc] == rows[i, acc]:
+                        acc += 1
+                    kept, reason = apply_finish(
+                        seq.sampler, seq.out, rows[i, :acc + 1],
+                        eos_row=done_rows[i, :acc + 1])
+                    seq.sampler.skip(kept)   # host stream stays aligned
+                    pos = int(q_start[i])
+                    seq.prefilled = pos + kept   # valid written KV only
+                    self.decode_tokens += kept
+                    self.truncated_tokens += 1 + len(draft) - kept
+                    self.spec_proposed += len(draft)
+                    self.spec_accepted += acc
+                    self.sched.spec_feedback(seq, len(draft), acc,
+                                             self.spec)
+                    if reason is not None:
+                        seq.finish_reason = reason
+                    # rejected tails (and finish tails) hand their
+                    # pre-extended pages back mid-step via the existing
+                    # truncate path
+                    self.reclaimed_pages += self.cache.truncate(
+                        seq.seq_id, pos + kept)
         self.decode_dispatches += 1
         self.spec_dispatches += 1
         return True
 
     def _reap_done(self) -> None:
-        for seq in list(self.sched.running):
-            if seq.done:
-                seq.finish_reason = seq.finish_reason or "length"
-                self.finish_reasons[seq.finish_reason] = (
-                    self.finish_reasons.get(seq.finish_reason, 0) + 1)
-                self._finished[seq.seq_id] = seq.out
-                self.sched.finish(seq)
+        with _span("engine.reap"):
+            for seq in list(self.sched.running):
+                if seq.done:
+                    seq.finish_reason = seq.finish_reason or "length"
+                    self.finish_reasons[seq.finish_reason] = (
+                        self.finish_reasons.get(seq.finish_reason, 0) + 1)
+                    self._finished[seq.seq_id] = seq.out
+                    self.sched.finish(seq)
 
     def step(self) -> None:
         """One engine iteration: admit, one prefill chunk, one decode
@@ -669,8 +706,9 @@ class PagedEngine:
         trace sharded engines with the sharding constraints active.
         """
         meshctx, rulectx = _run_ctx(self.rules)
-        with meshctx, rulectx:
-            self.sched.admit()
+        with meshctx, rulectx, _span("engine.step", step=self.steps):
+            with _span("engine.admit"):
+                self.sched.admit()
             seq = self.sched.next_prefill()
             if seq is not None:
                 self._prefill_step(seq)

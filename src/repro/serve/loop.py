@@ -41,6 +41,7 @@ import time
 import zlib
 from typing import Callable, Dict, Iterator, List, Optional
 
+import jax
 import numpy as np
 
 from repro.serve.engine import PagedEngine, Request
@@ -180,35 +181,43 @@ class AsyncEngine:
     # -- the loop -------------------------------------------------------------
 
     def _admit_due(self) -> None:
-        while self._pending and self._pending[0].arrival <= self.now:
-            h = self._pending.pop(0)
-            h._seq = self.engine.submit(h.request)
-            h.arrival_wall = time.perf_counter()
-            self._live[h._seq.seq_id] = h
+        with jax.profiler.TraceAnnotation("loop.admit") as span:
+            n = 0
+            while self._pending and self._pending[0].arrival <= self.now:
+                h = self._pending.pop(0)
+                h._seq = self.engine.submit(h.request)
+                h.arrival_wall = time.perf_counter()
+                self._live[h._seq.seq_id] = h
+                n += 1
+            span.set_metadata(n=n)
 
     def _drain(self) -> None:
         """Surface newly decoded tokens and reap finished handles."""
-        wall = time.perf_counter()
-        for sid, h in list(self._live.items()):
-            seq = h._seq
-            new = seq.out[h._streamed:]
-            for tok in new:
-                h.tokens.append(tok)
-                h.token_steps.append(self.now)
-                h.token_walls.append(wall)
-                if h.first_token_step is None:
-                    h.first_token_step = self.now
-                if h.on_token is not None:
-                    h.on_token(h, tok)
-            h._streamed = len(seq.out)
-            if seq.finish_reason is not None and sid not in (
-                    s.seq_id for s in self.engine.sched.running):
-                if sid in self.engine._finished:
-                    del self.engine._finished[sid]   # loop owns outputs
-                h.finish_reason = seq.finish_reason
-                h.finish_step = self.now
-                del self._live[sid]
-                self.completed.append(h)
+        with jax.profiler.TraceAnnotation("loop.drain") as span:
+            wall = time.perf_counter()
+            surfaced = 0
+            for sid, h in list(self._live.items()):
+                seq = h._seq
+                new = seq.out[h._streamed:]
+                surfaced += len(new)
+                for tok in new:
+                    h.tokens.append(tok)
+                    h.token_steps.append(self.now)
+                    h.token_walls.append(wall)
+                    if h.first_token_step is None:
+                        h.first_token_step = self.now
+                    if h.on_token is not None:
+                        h.on_token(h, tok)
+                h._streamed = len(seq.out)
+                if seq.finish_reason is not None and sid not in (
+                        s.seq_id for s in self.engine.sched.running):
+                    if sid in self.engine._finished:
+                        del self.engine._finished[sid]  # loop owns outputs
+                    h.finish_reason = seq.finish_reason
+                    h.finish_step = self.now
+                    del self._live[sid]
+                    self.completed.append(h)
+            span.set_metadata(tokens=surfaced)
 
     @property
     def has_work(self) -> bool:
@@ -217,16 +226,19 @@ class AsyncEngine:
     def step(self) -> None:
         """One loop iteration: admit due arrivals, run one engine step
         (or fast-forward an idle clock to the next arrival), surface
-        tokens."""
-        self._admit_due()
-        if self.engine.sched.has_work:
-            self.engine.step()
-        elif self._pending:
-            self.engine.steps = self._pending[0].arrival
+        tokens. The loop's host spans (``loop.step``, ``loop.admit``,
+        ``loop.drain``) and the engine's (``engine.*``) go to the
+        profiler's trace while one is collecting."""
+        with jax.profiler.TraceAnnotation("loop.step"):
             self._admit_due()
             if self.engine.sched.has_work:
                 self.engine.step()
-        self._drain()
+            elif self._pending:
+                self.engine.steps = self._pending[0].arrival
+                self._admit_due()
+                if self.engine.sched.has_work:
+                    self.engine.step()
+            self._drain()
 
     def run(self) -> List[RequestHandle]:
         """Drive the loop until drained; completed handles in finish
