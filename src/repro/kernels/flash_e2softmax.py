@@ -21,6 +21,7 @@ MXU alignment: block_q = block_k = 128+ and head_dim a multiple of 128
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -182,6 +183,43 @@ def flash_e2softmax_pallas(q, k, v, *, causal: bool = True,
 
 # -- paged variants (serve path: KV lives in a block-paged pool) --------------
 
+# VMEM that one grid step of the paged kernel may hold in its blocks and
+# scratch: three quarters of the v5e's 16 MiB default scoped limit, the
+# rest left to the body's temporaries.
+PAGED_VMEM_BUDGET = 12 * 2 ** 20
+
+
+def _vmem_bytes(shape, dtype) -> int:
+    """VMEM bytes of an array whose last two dims are padded to the
+    native tile: 128 lanes by 8 sublanes of 32 bits (16 bf16, 32 int8)."""
+    item = jnp.dtype(dtype).itemsize
+    *lead, rows, cols = shape
+    sub = 32 // item
+    return (math.prod(lead) * -(-rows // sub) * sub * -(-cols // 128) * 128
+            * item)
+
+
+def _step_vmem(hb, c, kvh, bs, d, q_dtype, kv_dtype) -> int:
+    """Blocks and scratch of one paged grid step: ``hb`` q heads of ``c``
+    rows each, against one page of ``kvh`` KV heads."""
+    blk = (hb, c, d)
+    return (2 * _vmem_bytes(blk, q_dtype)                 # q, two buffers
+            + 3 * _vmem_bytes(blk, jnp.float32)           # out (two), acc
+            + 4 * _vmem_bytes((kvh, bs, d), kv_dtype)     # K, V (two each)
+            + 2 * _vmem_bytes((hb, c), jnp.float32))      # m, s
+
+
+def paged_head_block(h: int, kvh: int, c: int, d: int, bs: int, q_dtype,
+                     kv_dtype) -> int:
+    """Q heads that one grid step of the paged kernel folds: the largest
+    divisor of ``h`` whose blocks and scratch, beside a page of all
+    ``kvh`` KV heads, fit ``PAGED_VMEM_BUDGET``."""
+    for hb in range(h, 1, -1):
+        if h % hb == 0 and _step_vmem(hb, c, kvh, bs, d, q_dtype,
+                                      kv_dtype) <= PAGED_VMEM_BUDGET:
+            return hb
+    return 1
+
 
 def _paged_kernel(meta_ref, table_ref, kvmap_ref, q_ref, k_ref, v_ref,
                   o_ref, m_ref, s_ref, acc_ref, *, causal: bool, sole: bool,
@@ -189,22 +227,28 @@ def _paged_kernel(meta_ref, table_ref, kvmap_ref, q_ref, k_ref, v_ref,
                   exact_corr: bool, scale: float, block_size: int,
                   num_blocks: int, kv_scale: Optional[float],
                   quant_pv: bool):
-    """Gather-by-page-table flash attention (one sequence per grid row).
+    """Gather-by-page-table flash attention, a block of heads at once.
 
-    Grid (B, H, NB). The k/v BlockSpec index maps read the page id from
-    the scalar-prefetched ``table_ref`` so each (b, j) step DMAs exactly
-    one KV page — the pool is never gathered into a contiguous cache.
-    ``meta_ref[b] = (q_start, kv_len)``: absolute position of q row 0 and
-    the number of valid keys (entries past kv_len are masked; their table
-    slots point at the null page 0). ``kvmap_ref[h]`` maps q head ``h``
-    to its pool KV head — the GQA grouping used to be the implicit
-    ``h // (H // KV)``, but under tensor parallelism the q heads a shard
-    holds need not start at pool head 0 (sharded Q over a *replicated*
-    KV pool when ``kv_heads`` is not divisible by the model axis), so
-    the map is explicit and scalar-prefetched.
+    Grid (B, H // hb, NB). A step holds ``hb`` q heads of one sequence and
+    one page of every KV head: the k/v BlockSpec index maps read the page
+    id from the scalar-prefetched ``table_ref``, so each (b, j) step DMAs
+    one ``(KV, block_size, d)`` tile, and the pool is never gathered into
+    a contiguous cache. ``meta_ref[b] = (q_start, kv_len)``: absolute
+    position of q row 0 and the number of valid keys (entries past
+    kv_len are masked; their table slots point at the null page 0, so
+    dead steps repeat one block index and fetch nothing).
+    ``kvmap_ref[h]`` maps q head ``h`` to its pool KV head: the
+    contiguous GQA grouping ``h // (H // KV)`` by default, but under
+    tensor parallelism the q heads a shard holds need not start at pool
+    head 0 (sharded Q over a *replicated* KV pool when ``kv_heads`` is
+    not divisible by the model axis), so the map is explicit and
+    scalar-prefetched. Each head's two contractions are its own
+    (C, d) x (d, block_size) and (C, block_size) x (block_size, d) dots,
+    so folded heads never mix; the online update runs on all heads' rows
+    at once, elementwise as before.
     """
-    b, j = pl.program_id(0), pl.program_id(2)
-    bq, d = q_ref.shape[2], q_ref.shape[3]
+    b, hi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    hb, c = q_ref.shape[1], q_ref.shape[2]
     bs = block_size
     q_start = meta_ref[b, 0]
     kv_len = meta_ref[b, 1]
@@ -218,30 +262,30 @@ def _paged_kernel(meta_ref, table_ref, kvmap_ref, q_ref, k_ref, v_ref,
     run = (j * bs) < kv_len
     if causal:
         # block fully masked iff every key col is beyond the last q row.
-        run &= (j * bs) <= (q_start + bq - 1)
+        run &= (j * bs) <= (q_start + c - 1)
+
+    # int8 page pools dequantize by kv_scale in-kernel. quant_pv (W8A8):
+    # P·V accumulates the raw int8 V codes — E2Softmax's probs are powers
+    # of two, so this is the hardware shift-accumulate — and kv_scale (a
+    # power of two, so bit-exact to distribute) moves into the final
+    # per-row output scale.
+    v_scale = None if quant_pv else kv_scale
+
+    def page(ref, i, sc):                              # (bs, d) f32
+        x = ref[0, kvmap_ref[hi * hb + i]].astype(jnp.float32)
+        return x if sc is None else x * sc
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32) * scale    # (bq, d)
-        k = k_ref[0, 0].astype(jnp.float32)            # (bs, d)
-        v = v_ref[0, 0].astype(jnp.float32)
-        if kv_scale is not None:                       # int8 page pools
-            k = k * kv_scale
-            # quant_pv (W8A8): P·V accumulates the raw int8 V codes —
-            # E2Softmax's probs are powers of two, so this is the
-            # hardware shift-accumulate — and kv_scale (a power of two,
-            # so bit-exact to distribute) moves into the final per-row
-            # output scale.
-            if not quant_pv:
-                v = v * kv_scale
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)        # (bq, bs)
-        cols = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bq, bs), 1)
+        logits = jnp.stack([jax.lax.dot_general(
+            q_ref[0, i].astype(jnp.float32) * scale, page(k_ref, i, kv_scale),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            for i in range(hb)])                       # (hb, c, bs)
+        cols = j * bs + jax.lax.broadcasted_iota(jnp.int32, (hb, c, bs), 2)
         mask = cols < kv_len
         if causal:
             rows = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, bs), 0)
+                jnp.int32, (hb, c, bs), 1)
             mask = mask & (rows >= cols)
         logits = jnp.where(mask, logits, NEG)
         m_new, w, corr = _online_update(
@@ -249,17 +293,17 @@ def _paged_kernel(meta_ref, table_ref, kvmap_ref, q_ref, k_ref, v_ref,
             int8_scale=int8_scale, exact_corr=exact_corr)
         m_ref[...] = m_new
         s_ref[...] = s_ref[...] * corr + jnp.sum(w, -1)
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
-                        + jax.lax.dot_general(
-                            w, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
+        pv = jnp.stack([jax.lax.dot_general(
+            w[i], page(v_ref, i, v_scale), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) for i in range(hb)])
+        acc_ref[...] = acc_ref[...] * corr[..., None] + pv
 
     @pl.when(j == num_blocks - 1)
     def _final():
         scale_out = _final_scale(s_ref[...], sole=sole)
         if quant_pv and kv_scale is not None:
             scale_out = scale_out * kv_scale
-        o_ref[0, 0] = acc_ref[...] * scale_out[:, None]
+        o_ref[0] = acc_ref[...] * scale_out[..., None]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -278,12 +322,12 @@ def flash_e2softmax_paged(q, k_pool, v_pool, tables, meta, *,
 
     Args:
       q: (B, H, C, d) — C query tokens per sequence (a prefill chunk, or
-        C=1 for decode). GQA is handled inside the index maps (no head
-        repeat is materialized).
+        C=1 for decode). GQA is handled inside the kernel (no head
+        repeat is materialized in HBM).
       k_pool, v_pool: (N, KV, block_size, d) — the shared page pool,
-        head-major so one (page, head) block is a whole (block_size, d)
-        tile (Mosaic needs a block's last two dims to equal the array's
-        or be (8, 128)-aligned).
+        head-major so one page of every KV head is one contiguous
+        (KV, block_size, d) tile (Mosaic needs a block's last two dims
+        to equal the array's or be (8, 128)-aligned).
       tables: (B, NB) int32 per-sequence page tables; unused slots must
         hold 0 (the reserved null page) so gathers stay in bounds.
       meta: (B, 2) int32 rows (q_start, kv_len) — absolute position of
@@ -296,33 +340,35 @@ def flash_e2softmax_paged(q, k_pool, v_pool, tables, meta, *,
         head 0 — the replicated-KV fallback for ``kv_heads`` not
         divisible by the model axis (see models/layers.paged_attend).
 
-    Returns (B, H, C, d) float32.
+    Each grid step folds ``paged_head_block`` q heads: all of them,
+    unless a long chunk's blocks would overflow VMEM. Returns
+    (B, H, C, d) float32.
     """
     interpret = resolve_interpret(interpret)
     bsz, h, c, d = q.shape
-    n, kvh, bs, _ = k_pool.shape
+    _, kvh, bs, _ = k_pool.shape
     nb = tables.shape[1]
     if kv_head_map is None:
         kv_head_map = jnp.arange(h, dtype=jnp.int32) // max(h // kvh, 1)
+    hb = paged_head_block(h, kvh, c, d, bs, q.dtype, k_pool.dtype)
+
+    def q_map(b, hi, j, *_):
+        return b, hi, 0, 0
+
+    def kv_map(b, hi, j, meta, tbl, kvm):
+        return tbl[b, j], 0, 0, 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(bsz, h, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, c, d),
-                         lambda b, hh, j, meta, tbl, kvm: (b, hh, 0, 0)),
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda b, hh, j, meta, tbl, kvm:
-                         (tbl[b, j], kvm[hh], 0, 0)),
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda b, hh, j, meta, tbl, kvm:
-                         (tbl[b, j], kvm[hh], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, c, d),
-                               lambda b, hh, j, meta, tbl, kvm: (b, hh, 0, 0)),
+        grid=(bsz, h // hb, nb),
+        in_specs=[pl.BlockSpec((1, hb, c, d), q_map),
+                  pl.BlockSpec((1, kvh, bs, d), kv_map),
+                  pl.BlockSpec((1, kvh, bs, d), kv_map)],
+        out_specs=pl.BlockSpec((1, hb, c, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((c,), jnp.float32),
-            pltpu.VMEM((c,), jnp.float32),
-            pltpu.VMEM((c, d), jnp.float32),
+            pltpu.VMEM((hb, c), jnp.float32),
+            pltpu.VMEM((hb, c), jnp.float32),
+            pltpu.VMEM((hb, c, d), jnp.float32),
         ],
     )
     kern = functools.partial(

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro import ops
+from repro.configs.base import ARCH_NAMES, get_config
 from repro.core.sole.quant import calibrate_ptf
 from repro.ops import oracles as K
 
@@ -243,6 +244,151 @@ def test_paged_int8_pool_dequant(rng):
         jnp.asarray(tables), jnp.asarray(ctxs), sole=False)
     np.testing.assert_allclose(np.asarray(out_q), np.asarray(out_f),
                                rtol=1e-6, atol=1e-6)
+
+
+# Folded paged kernel: one grid step holds every head of a lane (or a
+# VMEM-sized block of them) and one page of every KV head.
+# Lanes: ends mid-page, null (kv_len 0), whole pages, near the table end.
+FOLD_LENS = (37, 0, 32, 59)
+# a tensor-parallel shard's 12 q heads of 24 (8 a group) over a replicated
+# 3-head pool: the map starts mid-group and spans two KV heads.
+MID_GROUP_MAP = tuple((12 + i) // 8 for i in range(12))
+
+
+def _folded_inputs(rng, h, kvh, d, c, *, int8=False, bs=16, nb=4):
+    b = len(FOLD_LENS)
+    n = 1 + b * nb
+    kp, vp = _page_pool(rng, n, bs, kvh, d)
+    if int8:
+        from repro.models.layers import KV_INT8_SCALE
+        kp, vp = (jnp.clip(jnp.round(x / KV_INT8_SCALE), -127,
+                           127).astype(jnp.int8) for x in (kp, vp))
+    pages = rng.permutation(np.arange(1, n))
+    tables = np.zeros((b, nb), np.int32)
+    for i, kv_len in enumerate(FOLD_LENS):
+        live = -(-kv_len // bs)
+        tables[i, :live] = pages[i * nb:i * nb + live]
+    lens = np.asarray(FOLD_LENS, np.int32)
+    meta = np.stack([np.maximum(lens - c, 0), lens], 1).astype(np.int32)
+    q = jnp.asarray(rng.normal(0, 1, (b, h, c, d)).astype(np.float32))
+    return q, kp, vp, jnp.asarray(tables), jnp.asarray(meta)
+
+
+@pytest.mark.parametrize("h,kvh,d,c,kvmap,pool", [
+    (32, 32, 64, 1, None, "f32"),
+    (32, 32, 64, 16, None, "f32"),
+    (14, 2, 64, 1, None, "f32"),
+    (14, 2, 64, 16, None, "f32"),
+    (8, 2, 128, 1, None, "f32"),
+    (8, 2, 128, 16, None, "f32"),
+    (12, 3, 64, 1, MID_GROUP_MAP, "f32"),
+    (12, 3, 64, 16, MID_GROUP_MAP, "f32"),
+    (14, 2, 64, 1, None, "int8"),
+    (14, 2, 64, 16, None, "int8"),
+    (14, 2, 64, 1, None, "int8_pv"),
+], ids=["mha-decode", "mha-c16", "gqa14-decode", "gqa14-c16",
+        "gqa8-d128-decode", "gqa8-d128-c16", "map-mid-group-decode",
+        "map-mid-group-c16", "int8-decode", "int8-c16", "int8-quant-pv"])
+def test_paged_folded_matches_gathered_ref(rng, h, kvh, d, c, kvmap, pool):
+    """Head-folded paged kernel == gathered two-pass reference (exact
+    mode) per head, for MHA, GQA, an explicit head map that starts mid
+    group (the tensor-parallel replicated-KV fallback), decode and a
+    causal chunk, f32 and int8 pools; null lanes give zeros."""
+    from repro.kernels.flash_e2softmax import (  # repro: noqa RPR001
+        flash_e2softmax_paged)
+    from repro.models.layers import KV_INT8_SCALE
+    from repro.ops import registry
+    q, kp, vp, tables, meta = _folded_inputs(rng, h, kvh, d, c,
+                                             int8=pool != "f32")
+    kw = dict(causal=c > 1)
+    if pool != "f32":
+        kw.update(kv_scale=KV_INT8_SCALE, quant_pv=pool == "int8_pv")
+    if kvmap is not None:
+        kw["kv_head_map"] = jnp.asarray(kvmap, jnp.int32)
+    out = np.asarray(flash_e2softmax_paged(q, kp, vp, tables, meta,
+                                           sole=False, **kw))
+    ref = registry.resolve("paged_attention", "exact", "reference")(
+        jnp.moveaxis(q, 1, 2), kp, vp, tables, meta[:, 0], meta[:, 1], **kw)
+    ref = np.moveaxis(np.asarray(ref), 1, 2)
+    live = np.asarray(FOLD_LENS) > 0
+    np.testing.assert_allclose(out[live], ref[live], rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(out[~live], 0.0)
+
+
+@pytest.mark.parametrize("h,kvh,c", [(8, 2, 1), (8, 2, 16), (6, 6, 1)],
+                         ids=["gqa-decode", "gqa-c16", "mha-decode"])
+def test_paged_folded_heads_never_mix(rng, h, kvh, c):
+    """An H-head call is bit-identical to H single-head calls stacked,
+    each against its own KV head: folding changes which grid step does
+    the work, not the arithmetic (SOLE mode, quantized correction)."""
+    from repro.kernels.flash_e2softmax import (  # repro: noqa RPR001
+        flash_e2softmax_paged)
+    q, kp, vp, tables, meta = _folded_inputs(rng, h, kvh, 16, c)
+    kw = dict(causal=c > 1, sole=True)
+    out = np.asarray(flash_e2softmax_paged(q, kp, vp, tables, meta, **kw))
+    g = h // kvh
+    one = np.concatenate([np.asarray(flash_e2softmax_paged(
+        q[:, i:i + 1], kp[:, i // g:i // g + 1], vp[:, i // g:i // g + 1],
+        tables, meta, **kw)) for i in range(h)], 1)
+    np.testing.assert_array_equal(out, one)
+
+
+@pytest.mark.parametrize("h,kvh,hb,kvmap", [
+    (8, 2, 4, None), (4, 1, 2, None), (6, 6, 2, None),
+    (12, 3, 4, MID_GROUP_MAP)],
+    ids=["whole-groups", "part-group", "mha", "map-mid-group"])
+def test_paged_split_heads_match_folded(rng, monkeypatch, h, kvh, hb, kvmap):
+    """When VMEM forces a split, grid axis 1 walks blocks of ``hb`` heads
+    (whole GQA groups, part of one group, single MHA heads, or a map that
+    starts mid-group): each head reads its own KV head of the page, and
+    the result is bit-identical to the all-heads step."""
+    from repro.kernels import flash_e2softmax as fe  # repro: noqa RPR001
+    q, kp, vp, tables, meta = _folded_inputs(rng, h, kvh, 16, 16)
+    kw = dict(causal=True, sole=True)
+    if kvmap is not None:
+        kw["kv_head_map"] = jnp.asarray(kvmap, jnp.int32)
+    paged = fe.flash_e2softmax_paged.__wrapped__     # untraced: no jit cache
+    folded = np.asarray(paged(q, kp, vp, tables, meta, **kw))
+    monkeypatch.setattr(fe, "PAGED_VMEM_BUDGET", fe._step_vmem(
+        hb, 16, kvh, 16, 16, q.dtype, kp.dtype))
+    assert fe.paged_head_block(h, kvh, 16, 16, 16, q.dtype, kp.dtype) == hb
+    split = np.asarray(paged(q, kp, vp, tables, meta, **kw))
+    np.testing.assert_array_equal(split, folded)
+
+
+@pytest.mark.parametrize("name", ARCH_NAMES)
+def test_paged_head_block_folds_every_head_at_decode(name):
+    """At decode (C=1) every shipped config's heads fold into one grid
+    step, for bf16 and int8 pools."""
+    from repro.kernels.flash_e2softmax import (  # repro: noqa RPR001
+        paged_head_block)
+    cfg = get_config(name)
+    h, kvh, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    for q_dtype in (jnp.bfloat16, jnp.float32):
+        for kv_dtype in (q_dtype, jnp.int8):
+            assert paged_head_block(h, kvh, 1, d, 16, q_dtype,
+                                    kv_dtype) == h
+
+
+@pytest.mark.parametrize("h,kvh,d,c", [
+    (48, 8, 128, 256), (32, 8, 128, 256), (32, 32, 64, 256),
+    (14, 2, 64, 256), (16, 1, 256, 256), (14, 2, 64, 1024)])
+def test_paged_head_block_splits_within_budget(h, kvh, d, c):
+    """Long chunks split the heads: the largest divisor of H whose blocks
+    and scratch, beside a page of every KV head, stay within the VMEM
+    budget. Minitron's published 48/8 heads at a 256-row chunk must
+    split."""
+    from repro.kernels import flash_e2softmax as fe  # repro: noqa RPR001
+
+    def vmem(n):
+        return fe._step_vmem(n, c, kvh, 16, d, jnp.bfloat16, jnp.bfloat16)
+    hb = fe.paged_head_block(h, kvh, c, d, 16, jnp.bfloat16, jnp.bfloat16)
+    assert h % hb == 0 and vmem(hb) <= fe.PAGED_VMEM_BUDGET
+    if hb < h:      # the next larger divisor of H would not fit
+        bigger = next(n for n in range(hb + 1, h + 1) if h % n == 0)
+        assert vmem(bigger) > fe.PAGED_VMEM_BUDGET
+    if (h, kvh, c) == (48, 8, 256):
+        assert hb < h
 
 
 def test_flash_exact_corr_beyond_paper(rng):

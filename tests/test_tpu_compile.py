@@ -25,6 +25,8 @@ from jax.sharding import SingleDeviceSharding
 # (heads, kv_heads, head_dim) of the served configs
 QWEN2_0_5B = (14, 2, 64)
 MINITRON_8B = (32, 8, 128)
+STABLELM_1_6B = (32, 32, 64)
+NEMOTRON_4_15B = (48, 8, 128)
 BATCH, BLOCK, MAX_SEQ = 8, 16, 544
 
 
@@ -65,12 +67,20 @@ def _compile(fn, sharding, *shapes):
     (QWEN2_0_5B, 1, "int8"),
     (QWEN2_0_5B, 16, "int8"),
     (MINITRON_8B, 1, "bfloat16"),
+    (STABLELM_1_6B, 1, "bfloat16"),
+    (STABLELM_1_6B, 256, "bfloat16"),
+    (MINITRON_8B, 256, "bfloat16"),
+    (NEMOTRON_4_15B, 256, "bfloat16"),
 ], ids=["qwen2-decode-bf16", "qwen2-prefill-bf16", "qwen2-decode-int8",
-        "qwen2-prefill-int8", "minitron-decode-bf16"])
+        "qwen2-prefill-int8", "minitron-decode-bf16", "stablelm-decode-bf16",
+        "stablelm-prefill256-bf16", "minitron-prefill256-bf16",
+        "nemotron-prefill256-bf16"])
 def test_paged_attention_compiles(one_chip, heads, c, pages):
-    """Decode (C=1) and prefill-chunk (C=16) paged E2Softmax attention
-    over a head-major page pool, bf16 and int8 pages (the int8 pool
-    dequantizes in-kernel and keeps V as codes through P·V)."""
+    """Decode (C=1) and prefill-chunk (C=16, 256) paged E2Softmax
+    attention over a head-major page pool, bf16 and int8 pages (the int8
+    pool dequantizes in-kernel and keeps V as codes through P·V). Every
+    head folds into one grid step at decode; 256-row chunks split the
+    heads into blocks that fit VMEM, which an overflow here would show."""
     from repro.kernels.flash_e2softmax import (  # repro: noqa RPR001
         flash_e2softmax_paged)
     from repro.models.layers import KV_INT8_SCALE
@@ -85,6 +95,27 @@ def test_paged_attention_compiles(one_chip, heads, c, pages):
     _compile(fn, one_chip, ((BATCH, h, c, d), "bfloat16"),
              ((n, kvh, BLOCK, d), pages), ((n, kvh, BLOCK, d), pages),
              ((BATCH, nb), "int32"), ((BATCH, 2), "int32"))
+
+
+@pytest.mark.parametrize("c", [1, 16], ids=["decode", "prefill"])
+def test_paged_attention_head_map_compiles(one_chip, c):
+    """The tensor-parallel replicated-KV fallback: a shard's 3 q heads of
+    nemotron_4_15b (48/8 heads on a 16-way model axis) attend the full 8-head
+    pool through an explicit q-head -> KV-head map."""
+    from repro.kernels.flash_e2softmax import (  # repro: noqa RPR001
+        flash_e2softmax_paged)
+    _, kvh, d = NEMOTRON_4_15B
+    nb = -(-MAX_SEQ // BLOCK)
+    n = BATCH * nb + 1
+
+    def fn(q, k, v, tables, meta, kvmap):
+        return flash_e2softmax_paged(
+            q, k, v, tables, meta, kv_head_map=kvmap, causal=c > 1,
+            sole=True, interpret=False)  # repro: noqa RPR002
+    _compile(fn, one_chip, ((BATCH, 3, c, d), "bfloat16"),
+             ((n, kvh, BLOCK, d), "bfloat16"),
+             ((n, kvh, BLOCK, d), "bfloat16"), ((BATCH, nb), "int32"),
+             ((BATCH, 2), "int32"), ((3,), "int32"))
 
 
 @pytest.mark.parametrize("rows,d", [(8, 896), (300, 896), (8, 4096)])
