@@ -56,6 +56,26 @@ def first_ranked(params, cfg, seqs):
     return out
 
 
+def compare(cell, seed: int, seqs) -> dict:
+    """The program's and the control's numbers on the sampled ``seqs``
+    of a run of ``seed``, on the weights the configuration's family makes
+    from the seed, and the control judged by the cell's limits."""
+    from harness import check, runner, serve
+    params = runner.make_weights(cell, seed)
+    picks = {"served": [x for _, _, x in seqs],
+             "control": first_ranked(
+                 params, serve.arch_config(cell, quant="w8a8"), seqs)}
+    g = check.gaps(cell.family, params, check.reference_model(cell), seqs,
+                   picks)
+    del params
+    gc.collect()
+    row = {name: check.numbers(v) for name, v in g.items()}
+    row["control_correct"], row["control_checks"] = check.judge(
+        row["control"], cell.load["check"]["limits"])
+    row["tokens"] = int(len(g["served"]))
+    return row
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -65,7 +85,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     cell = spec.load(ROOT, args.workload)
     sys.path.insert(0, str(ROOT / "src"))
-    from harness import check, device, runner, serve, weights
+    from harness import device, runner
     dev = device.info()
     try:
         device.require_chips(dev, cell.chips)
@@ -73,8 +93,6 @@ def main(argv=None) -> int:
         print(f"control: {e}", file=sys.stderr)
         return 3
     runner.enable_cache()
-    ref = check.reference_module(cell)
-    model = check.reference_model(cell)
     lines = []
     for seed in (int(s) for s in args.seeds.split(",")):
         keep: dict = {}
@@ -86,19 +104,7 @@ def main(argv=None) -> int:
                "metrics": out["metrics"], "served": None, "control": None,
                "control_correct": None}
         if seqs:
-            params = weights.make(cell.model, seed)
-            picks = {"served": [x for _, _, x in seqs],
-                     "control": first_ranked(
-                         params, serve.arch_config(cell, quant="w8a8"),
-                         seqs)}
-            g = check.gaps(ref, params, model, seqs, picks)
-            del params
-            gc.collect()
-            for name, v in g.items():
-                row[name] = check.numbers(v)
-            row["control_correct"], row["control_checks"] = check.judge(
-                row["control"], cell.load["check"]["limits"])
-            row["tokens"] = int(len(g["served"]))
+            row.update(compare(cell, seed, seqs))
         print("CONTROL " + json.dumps(row), flush=True)
         lines.append(row)
     if args.out:

@@ -11,19 +11,9 @@ about the spread of the logits.
 """
 from __future__ import annotations
 
-import importlib.util
 from typing import Dict, List
 
 import numpy as np
-
-
-def reference_module(cell):
-    path = cell.root / "bench" / "reference" / f"{cell.config['reference']}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_reference_{path.stem}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def reference_model(cell) -> dict:
@@ -65,9 +55,9 @@ def sequences(chosen) -> List[tuple]:
 
 
 def gaps(ref, weights, model: dict, seqs, picks: Dict[str, list]):
-    """Concatenated gaps per pick list over all sampled sequences;
-    ``picks[name][i]`` are the tokens chosen at sequence i's compared
-    positions."""
+    """Concatenated gaps per pick list over all sampled sequences, by the
+    family module ``ref``'s ``logit_gaps``; ``picks[name][i]`` are the
+    tokens chosen at sequence i's compared positions."""
     out: Dict[str, list] = {name: [] for name in picks}
     for i, (seq, first, _) in enumerate(seqs):
         g = ref.logit_gaps(weights, model, seq, first,

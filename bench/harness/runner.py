@@ -14,7 +14,8 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from harness import check, device, drive, serve, spec, trace, traffic, weights
+from harness import (check, device, drive, scopes, serve, spec, trace,
+                     traffic)
 
 
 def log(*parts) -> None:
@@ -117,6 +118,22 @@ def enable_cache() -> None:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 
+def make_weights(cell: spec.Cell, seed: int):
+    """The served tree for ``seed``, as the configuration's family makes
+    it, on the device."""
+    import jax
+    return jax.block_until_ready(cell.family.make(cell.model, seed))
+
+
+def reduce_trace(tdir: Path, scope_names) -> dict:
+    """What a per-layer reader finds under ``ctx.trace``: ``trace.reduce``
+    by the bench's spans, and beside it ``scopes.reduce`` by the
+    program's spans and the family's scope names. Their keys are apart,
+    so neither changes what the other's readers see."""
+    return {**trace.reduce(tdir, drive.SPANS),
+            **scopes.reduce(tdir, scope_names)}
+
+
 def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
              dev: dict, t_start: float,
              after_build: Optional[Callable] = None,
@@ -125,8 +142,6 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     ``after_build(engine)`` runs once the engine exists (tests use it to
     break the timed path); ``keep``, if given, receives the window's
     request records and the sampled sequences of the check."""
-    import jax
-
     from repro.serve.loop import AsyncEngine
     compiles = _Compiles()
     load, mix, model = cell.load, cell.traffic, cell.model
@@ -136,8 +151,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     if dev["platform"] == "tpu" and backends["paged_attention"] != "pallas":
         raise RuntimeError(f"paged attention runs no kernel: {backends}")
 
-    params = weights.make(model, seed)
-    jax.block_until_ready(params)
+    params = make_weights(cell, seed)
     log(f"weights made: {time.perf_counter() - t_start:.3f} s after start")
     eng = serve.build(cell, cfg, params)
     del params
@@ -196,13 +210,14 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
                    for m in cell.end_to_end}
     breakdown = None
     if traced:
-        red = trace.reduce(tdir, drive.SPANS)
+        red = reduce_trace(tdir, cell.family.SCOPES)
+        shutil.rmtree(tdir, ignore_errors=True)
         log("device idle by host span (s):",
             json.dumps(red["idle_by_host_span"]))
-        shutil.rmtree(tdir, ignore_errors=True)
+        log("device seconds by step and scope:", json.dumps(red["by_scope"]))
         dev_out.update(busy_s=red["busy_s"], window_s=red["window_s"])
         breakdown = {"device_ops": red["top_ops"],
-                     "idle_gaps": red["idle_gaps"]}
+                     "idle_gaps": red["scoped_idle_gaps"]}
         # what a per-layer metric reader may read
         peak = (device.peaks(dev["kind"]) if dev["platform"] == "tpu"
                 else None)
@@ -210,7 +225,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
             cell=cell, model=model, records=records,
             window=(win.open, win.close), end=end, traced=win.span,
             stats_open=win.stats_open, stats_close=win.stats_close,
-            trace=red, e2e=e2e, peak=peak)
+            work=cell.family.work(model), trace=red, e2e=e2e, peak=peak)
         out_metrics = {}
         readers = spec.readers(cell)
         for m in cell.per_layer:
@@ -289,10 +304,9 @@ def _check(cell, seed, records, window, keep=None):
     seqs = check.sequences(chosen)
     if keep is not None:
         keep.update(records=records, seqs=seqs)
-    ref = check.reference_module(cell)
-    params = weights.make(cell.model, seed)
+    params = make_weights(cell, seed)
     t0 = time.perf_counter()
-    g = check.gaps(ref, params, check.reference_model(cell), seqs,
+    g = check.gaps(cell.family, params, check.reference_model(cell), seqs,
                    {"served": [s for _, _, s in seqs]})["served"]
     vals = check.numbers(g)
     correct, checks = check.judge(vals, cfg_check["limits"])

@@ -2,14 +2,15 @@
 
 The served path names its work twice on the profiler's clock:
 
-- device scopes: ``models/transformer.py`` runs each sublayer of the
-  paged forward and the decode horizon under a ``jax.named_scope``
-  (``SCOPES``). The scope is a component of the op's ``op_name`` path,
-  which a TPU trace keeps in the stat ``OP_PATH_STAT`` of each ``XLA
-  Ops`` event's metadata (read by hand on a v5e trace). Each op gets the
-  first of the scope names in its path, or ``unscoped`` (ops XLA adds,
-  such as copies of a loop's carried state, have no path of the
-  program's);
+- device scopes: the program runs each sublayer of the paged forward
+  and the decode horizon under a ``jax.named_scope``, whose names the
+  configuration's family module lists (``SCOPES`` in
+  ``bench/reference/<family>.py``). The scope is a component of the
+  op's ``op_name`` path, which a TPU trace keeps in the stat
+  ``OP_PATH_STAT`` of each ``XLA Ops`` event's metadata (read by hand on
+  a v5e trace). Each op gets the first of the scope names in its path,
+  or ``unscoped`` (ops XLA adds, such as copies of a loop's carried
+  state, have no path of the program's);
 - host spans: ``jax.profiler.TraceAnnotation`` spans of the bench
   (``bench.*``, ``harness/drive.py``), the loop (``loop.*``,
   ``serve/loop.py``) and the engine (``engine.*``, ``serve/engine.py``),
@@ -29,8 +30,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from harness import trace
 
-SCOPES = ("embed", "norm", "qkv", "kv_write", "paged_attention",
-          "attn_out", "mlp", "lm_head", "sample")
 UNSCOPED = "unscoped"
 OP_PATH_STAT = "tf_op"
 SPAN_PREFIXES = ("bench.", "loop.", "engine.")
@@ -38,15 +37,17 @@ SPAN_PREFIXES = ("bench.", "loop.", "engine.")
 Span = Tuple[str, float, float]
 
 
-def scope_of(path: Optional[str]) -> str:
-    """``jit(_decode_h)/while/body/kv_write/scatter`` -> ``kv_write``."""
+def scope_of(path: Optional[str], names: Sequence[str]) -> str:
+    """``jit(_decode_h)/while/body/kv_write/scatter`` -> ``kv_write``
+    where ``kv_write`` is one of the scope ``names``."""
     for part in (path or "").split("/"):
-        if part in SCOPES:
+        if part in names:
             return part
     return UNSCOPED
 
 
-def by_scope(devices: Sequence[dict]) -> Dict[str, Dict[str, float]]:
+def by_scope(devices: Sequence[dict],
+             names: Sequence[str]) -> Dict[str, Dict[str, float]]:
     """{step kind: {scope: device seconds}} per chip, from ``devices``:
     one dict per chip, {"modules": [(name, start, end)], "ops":
     [(name, start, end, op_name path)]}. An op belongs to the jitted
@@ -62,7 +63,7 @@ def by_scope(devices: Sequence[dict]) -> Dict[str, Dict[str, float]]:
             mid = (s + e) / 2
             kind = next((k for a, b, k in mods if a <= mid <= b), None)
             table = out.setdefault(str(kind), {})
-            scope = scope_of(path)
+            scope = scope_of(path, names)
             table[scope] = table.get(scope, 0.0) + (e - s) / 1e9
     n = max(len(devices), 1)
     return {k: {s: v / n for s, v in t.items()} for k, t in out.items()}
@@ -118,16 +119,17 @@ def host_seconds(host: Sequence[Span], window_ns: float) -> float:
 
 
 def reduce_events(devices: Sequence[dict], host: Sequence[Span],
-                  window_ns: float) -> dict:
+                  window_ns: float, names: Sequence[str]) -> dict:
     """The reduction, from plain events (times in ns from the window's
-    start); ``host`` holds (span name, start, end)."""
+    start); ``host`` holds (span name, start, end), ``names`` the scope
+    names."""
     gaps = idle_gaps(devices, host, window_ns)
     n = max(len(devices), 1)
     by_label: Dict[str, float] = {}
     for label, sec in gaps:
         by_label[label] = by_label.get(label, 0.0) + sec / n
     return {
-        "by_scope": by_scope(devices),
+        "by_scope": by_scope(devices, names),
         "scoped_idle_gaps": [[k, v] for k, v in
                              sorted(gaps, key=lambda kv: -kv[1])[:10]],
         "idle_by_innermost_span": by_label,
@@ -177,9 +179,10 @@ def read(trace_dir: Path):
     return devices, [(n, s - lo, e - lo) for n, s, e in host], hi - lo
 
 
-def reduce(trace_dir: Path) -> dict:
-    """``reduce_events`` of the trace written under ``trace_dir``."""
-    return reduce_events(*read(trace_dir))
+def reduce(trace_dir: Path, names: Sequence[str]) -> dict:
+    """``reduce_events`` of the trace written under ``trace_dir``, by the
+    scope ``names``."""
+    return reduce_events(*read(trace_dir), names)
 
 
 def op_paths(buf) -> Dict[str, Dict[str, str]]:

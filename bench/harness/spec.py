@@ -5,14 +5,20 @@ with the engine settings a deployment would choose),
 (the offered load and the limits of the output check) and
 ``bench/metrics/<metric>.py`` (one reader per per-layer metric; a
 reader named for the part of the name before the first dot serves
-every metric of that quantity without one of its own).
+every metric of that quantity without one of its own), and
+``bench/reference/<family>.py``, the family module that the config
+names under ``"reference"``: the weight tree, the work counts, the
+device scope names and the plain reference of its model
+(``bench/reference/dense.py`` says what each is).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List
 
 
@@ -39,6 +45,11 @@ class Cell:
     @property
     def engine(self) -> dict:
         return self.config["engine"]
+
+    @functools.cached_property
+    def family(self) -> ModuleType:
+        """The configuration's family module, loaded once per cell."""
+        return family_module(self.root, self.config["reference"])
 
 
 def _json(path: Path) -> dict:
@@ -70,17 +81,32 @@ def load(root: Path, workload: str) -> Cell:
         root=root)
 
 
+def _module(path: Path, name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def family_module(root: Path, name: str) -> ModuleType:
+    """``bench/reference/<name>.py``: ``shapes(model)`` and
+    ``make(model, seed)`` of the served weight tree, ``work(model)`` for
+    ``harness/work.py``, ``SCOPES`` for ``harness/scopes.py`` and
+    ``logit_gaps(...)``, the plain reference."""
+    path = root / "bench" / "reference" / f"{name}.py"
+    if not path.is_file():
+        raise SpecError(f"missing {path}")
+    return _module(path, f"bench_reference_{name}")
+
+
 def metric_reader(root: Path, name: str) -> Callable:
     """``read(ctx) -> float | None`` of a per-layer metric."""
     base = root / "bench" / "metrics"
     for stem in (name, name.split(".")[0]):
         path = base / f"{stem}.py"
         if path.is_file():
-            spec = importlib.util.spec_from_file_location(
-                f"bench_metric_{stem.replace('.', '_')}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
+            return _module(path,
+                           f"bench_metric_{stem.replace('.', '_')}").read
     raise SpecError(f"no reader for per-layer metric {name!r} in {base}")
 
 

@@ -6,6 +6,19 @@ padded page table, not null lanes, not once per q head of a GQA
 group); a norm reads its inputs and writes its outputs once in the
 model dtype. A kernel that fetches less than it does today therefore
 raises its roofline share, and one that fetches more lowers it.
+
+What a token's work is made of comes from the configuration's family
+module (``work(model)`` in ``bench/reference/<family>.py``), as a dict:
+
+- ``block_params``: the weights a token multiplies in the blocks (an
+  MoE counts the experts a token visits, leading dense layers at their
+  own width);
+- ``attention``: one ``(q heads, KV heads, head size, window)`` per
+  attention layer, window 0 for the whole context;
+- ``norms``: how many norms a token passes through, each over
+  ``width`` channels of the residual stream;
+- ``head_params``: the LM head's weights, multiplied once per row whose
+  next token is needed.
 """
 from __future__ import annotations
 
@@ -19,50 +32,56 @@ def roofline_s(flops: float, nbytes: float, peak: dict) -> float:
     return max(flops / peak["bf16_flops"], nbytes / peak["hbm_bytes_per_s"])
 
 
-def attention(model: dict, queries: Iterable[Tuple[int, int]]):
-    """(flops, bytes) of paged attention over all layers for query rows
-    given as (first position, row count) spans of one lane: each row at
-    position p attends keys 0..p (causal), and the span reads the
-    keys and values of its whole context once."""
-    h, kv, hd = model["n_heads"], model["n_kv_heads"], model["head_dim"]
+def _pairs_and_keys(start: int, n: int, window: int) -> Tuple[int, int]:
+    """(query-key pairs, keys read) of rows at positions start .. start
+    + n - 1, the row at p attending keys 0..p, or its last ``window``."""
+    keys = start + n
+    # sum over rows of (p + 1) keys, p = start .. start + n - 1
+    pairs = n * start + n * (n + 1) // 2
+    if not window or keys <= window:
+        return pairs, keys
+    # rows see min(p + 1, window) keys: full up to p + 1 = window
+    lo = min(start, window)
+    pairs = window * (window + 1) // 2 - lo * (lo + 1) // 2 \
+        + window * (keys - max(start, window))
+    return pairs, min(keys, n + window - 1)
+
+
+def attention(counts: dict, queries: Iterable[Tuple[int, int]]):
+    """(flops, bytes) of paged attention over every attention layer for
+    query rows given as (first position, row count) spans of one lane:
+    each row at position p attends keys 0..p (causal), or the last
+    ``window`` of them, and the span reads the keys and values of its
+    context once per KV head."""
     flops = nbytes = 0
-    for start, n in queries:
-        keys = start + n
-        # sum over rows of (p + 1) keys, p = start .. start + n - 1
-        pairs = n * start + n * (n + 1) // 2
-        flops += 4 * h * hd * pairs                   # q.k and w.v
-        nbytes += ACT_BYTES * (2 * kv * hd * keys     # K and V
-                               + 2 * h * hd * n)      # q in, context out
-    layers = model["n_layers"]
-    return flops * layers, nbytes * layers
+    queries = list(queries)
+    for h, kv, hd, window in counts["attention"]:
+        for start, n in queries:
+            pairs, keys = _pairs_and_keys(start, n, window)
+            flops += 4 * h * hd * pairs                   # q.k and w.v
+            nbytes += ACT_BYTES * (2 * kv * hd * keys     # K and V
+                                   + 2 * h * hd * n)      # q in, context out
+    return flops, nbytes
 
 
-def norms(model: dict, rows: int):
+def norms(counts: dict, rows: int):
     """(flops, bytes) of every norm of a forward pass over ``rows``
     tokens: the first reads x and writes its output; the others add the
     residual (read x and r, write the sum and the output)."""
-    tensors = 2 + 4 * 2 * model["n_layers"]
-    return 0, rows * model["d_model"] * ACT_BYTES * tensors
+    tensors = 2 + 4 * (counts["norms"] - 1)
+    return 0, rows * counts["width"] * ACT_BYTES * tensors
 
 
-def layer_params(model: dict) -> int:
-    """Weights a token multiplies in the blocks (projections and MLP)."""
-    d, f = model["d_model"], model["d_ff"]
-    h, kv, hd = model["n_heads"], model["n_kv_heads"], model["head_dim"]
-    per_layer = d * (h + 2 * kv) * hd + h * hd * d + 3 * d * f
-    return model["n_layers"] * per_layer
-
-
-def model_flops(model: dict, queries: Iterable[Tuple[int, int]],
+def model_flops(counts: dict, queries: Iterable[Tuple[int, int]],
                 head_rows: int) -> float:
     """Model FLOPs of the given query spans: 2 per weight per token in
     every block, attention over the live context, and the LM head for
     ``head_rows`` rows (the rows whose next token is needed)."""
     queries = list(queries)
     tokens = sum(n for _, n in queries)
-    head = 2 * model["d_model"] * model["vocab_size"] * head_rows
-    return (2 * layer_params(model) * tokens
-            + attention(model, queries)[0] + head)
+    head = 2 * counts["head_params"] * head_rows
+    return (2 * counts["block_params"] * tokens
+            + attention(counts, queries)[0] + head)
 
 
 def served_spans(records, t0: float, t1: float, chunk: int):

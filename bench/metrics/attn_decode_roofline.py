@@ -13,5 +13,5 @@ def read(ctx):
                                      ctx.cell.engine["prefill_chunk"])
     if not decode:
         return None
-    f, b = work.attention(ctx.model, decode)
+    f, b = work.attention(ctx.work, decode)
     return 100.0 * work.roofline_s(f, b, ctx.peak) / t

@@ -15,6 +15,6 @@ def read(ctx):
         ctx.records, *ctx.traced, ctx.cell.engine["prefill_chunk"])
     if not decode and not prefill:
         return None
-    f = work.model_flops(ctx.model, decode + prefill,
+    f = work.model_flops(ctx.work, decode + prefill,
                          head_rows=len(decode) + firsts)
     return 100.0 * f / (t["window_s"] * ctx.peak["bf16_flops"])

@@ -15,5 +15,5 @@ def read(ctx):
                                      ctx.cell.engine["prefill_chunk"])
     if not decode:
         return None
-    f, b = work.norms(ctx.model, len(decode))
+    f, b = work.norms(ctx.work, len(decode))
     return 100.0 * work.roofline_s(f, b, ctx.peak) / t

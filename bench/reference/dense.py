@@ -1,9 +1,18 @@
-"""Plain reference of a dense decoder LM (qwen2 / stablelm shape): token
+"""The dense family: a decoder LM of the qwen2 / stablelm shape, token
 embedding, pre-norm blocks of RoPE attention with QKV bias and a SwiGLU
 MLP, a final norm and an untied LM head, with softmax and norms exact or
 SOLE as the configuration's ``softmax_mode`` / ``norm_mode`` say.
 
-It states the arithmetic that the served path defines, in plain
+A configuration names its family module under ``"reference"``, and the
+harness takes from it all it knows of the model:
+
+* the weight tree the served path reads (``shapes``, ``make``);
+* the work a token does (``work``), which ``harness/work.py`` counts;
+* the names of the device scopes its forward runs under (``SCOPES``),
+  by which ``harness/scopes.py`` reduces a trace;
+* the plain reference (``logit_gaps``), which the output check runs.
+
+The reference states the arithmetic that the served path defines, in plain
 ``jax.numpy``, and imports nothing of the program:
 
 * Storage is bfloat16 wherever the configuration's dtype puts a tensor
@@ -48,6 +57,97 @@ ALDIV_BIAS = 1.636        # ALDivision's unbiasing constant
 LOGIT_INT8_SCALE = 12.0 / 127.0
 ROW_BLOCK = 256           # attention rows per block
 T_BUCKET = 512            # sequences are padded to a multiple of this
+# the program's paged forward, decode horizon and verify step
+# (models/transformer.py) run each sublayer under one of these
+SCOPES = ("embed", "norm", "qkv", "kv_write", "paged_attention",
+          "attn_out", "mlp", "lm_head", "sample")
+
+
+def shapes(model: dict) -> dict:
+    """Leaf name -> (shape, kind) of the served tree."""
+    d, f = model["d_model"], model["d_ff"]
+    h, kv, hd = model["n_heads"], model["n_kv_heads"], model["head_dim"]
+    n, vp = model["n_layers"], -(-model["vocab_size"] // 128) * 128
+
+    def norm_leaves(prefix, lead):
+        out = {f"{prefix}.g": (lead + (d,), "gain")}
+        if model["norm_kind"] == "layernorm":
+            out[f"{prefix}.b"] = (lead + (d,), "bias")
+        return out
+
+    leaves = {"embed.table": ((vp, d), "proj"),
+              "embed.head": ((d, vp), "head")}
+    for ln in ("ln1", "ln2"):
+        leaves.update(norm_leaves(f"layers.{ln}", (n,)))
+    leaves.update({
+        "layers.attn.wq": ((n, d, h, hd), "proj"),
+        "layers.attn.wk": ((n, d, kv, hd), "proj"),
+        "layers.attn.wv": ((n, d, kv, hd), "proj"),
+        "layers.attn.wo": ((n, h, hd, d), "proj"),
+        "layers.mlp.gate": ((n, d, f), "proj"),
+        "layers.mlp.up": ((n, d, f), "proj"),
+        "layers.mlp.down": ((n, f, d), "proj"),
+    })
+    if model["qkv_bias"]:
+        leaves.update({"layers.attn.bq": ((n, h, hd), "bias"),
+                       "layers.attn.bk": ((n, kv, hd), "bias"),
+                       "layers.attn.bv": ((n, kv, hd), "bias")})
+    leaves.update(norm_leaves("final_norm", ()))
+    return leaves
+
+
+def _nest(flat: dict) -> dict:
+    tree: dict = {}
+    for path, value in flat.items():
+        node = tree
+        *parents, leaf = path.split(".")
+        for p in parents:
+            node = node.setdefault(p, {})
+        node[leaf] = value
+    return tree
+
+
+@functools.partial(jax.jit, static_argnames=("spec",))
+def _make(key, spec):
+    flat = {}
+    for i, (path, shape, kind, d) in enumerate(spec):
+        z = jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
+        if kind == "gain":
+            v = 1.0 + 0.1 * z
+        elif kind == "head":
+            v = z * d ** -0.5
+        else:
+            v = 0.02 * z
+        flat[path] = v.astype(jnp.bfloat16)
+    return _nest(flat)
+
+
+def make(model: dict, seed: int) -> dict:
+    """The bf16 weight tree for ``seed`` (any non-negative integer), made
+    on the device in one jitted call. Projections are N(0, 0.02); the LM
+    head N(0, 1/d_model) so logits have unit scale; norm gains
+    1 + N(0, 0.1), norm and QKV biases N(0, 0.02). Non-trivial gains and
+    biases make the comparison cover those paths."""
+    spec = tuple((path, shape, kind, model["d_model"])
+                 for path, (shape, kind) in shapes(model).items())
+    key = jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF),
+                             (seed >> 32) & 0x7FFFFFFF)
+    return _make(key, spec)
+
+
+def work(model: dict) -> dict:
+    """The work a token does, in the terms ``harness/work.py`` counts:
+    every layer is an attention layer over the whole context, a norm
+    before its attention and its MLP, and one after the last."""
+    d, f = model["d_model"], model["d_ff"]
+    h, kv, hd = model["n_heads"], model["n_kv_heads"], model["head_dim"]
+    n = model["n_layers"]
+    return {"block_params": n * (d * (h + 2 * kv) * hd + h * hd * d
+                                 + 3 * d * f),
+            "attention": [(h, kv, hd, 0)] * n,
+            "norms": 2 * n + 1,
+            "width": d,
+            "head_params": d * model["vocab_size"]}
 
 
 def _bf16(x):

@@ -17,7 +17,7 @@ sys.path.insert(0, str(BENCH.parent / "src"))
 
 import numpy as np  # noqa: E402
 
-from harness import check, serve, spec, traffic, weights  # noqa: E402
+from harness import check, runner, serve, spec, traffic  # noqa: E402
 
 # four layers at a quarter of qwen2_0_5b's width, head_dim as published
 MODEL = {"n_layers": 4, "d_model": 256, "n_heads": 4, "n_kv_heads": 2,
@@ -67,7 +67,7 @@ def test_control_reads_above_the_sound_run(tmp_path):
     cell = spec.load(tmp_path, "mid.chat")
 
     from repro.serve.engine import Request
-    params = weights.make(cell.model, SEED)
+    params = runner.make_weights(cell, SEED)
     eng = serve.build(cell, serve.arch_config(cell), params)
     reqs = traffic.Generator(MIX, SEED, MODEL["vocab_size"]).open_loop(
         6, 0, 2)
@@ -76,13 +76,11 @@ def test_control_reads_above_the_sound_run(tmp_path):
     seqs = [(np.concatenate([r.prompt, o]).astype(np.int32),
              len(r.prompt) - 1, np.asarray(o, np.int32))
             for r, o in zip(reqs, outs)]
-    control = _control_module().first_ranked(
-        params, serve.arch_config(cell, quant="w8a8"), seqs)
-    g = check.gaps(check.reference_module(cell), params,
-                   check.reference_model(cell), seqs,
-                   {"served": [s for _, _, s in seqs], "control": control})
-    served, ctl = check.numbers(g["served"]), check.numbers(g["control"])
-    assert len(g["served"]) >= 300
+    del eng, params
+    row = _control_module().compare(cell, SEED, seqs)
+    served, ctl = row["served"], row["control"]
+    assert row["tokens"] >= 300
     assert ctl["mean_gap"] > 3 * served["mean_gap"], (served, ctl)
     assert check.judge(served, LIMITS)[0] is True, (served, ctl)
     assert check.judge(ctl, LIMITS)[0] is False, (served, ctl)
+    assert row["control_correct"] is False

@@ -10,9 +10,10 @@ import pytest
 BENCH = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 
-from harness import device, scopes, spec, trace, work  # noqa: E402
+from harness import device, drive, runner, scopes, spec, trace, work  # noqa: E402
 
 MS = 1_000_000_000          # picoseconds in a millisecond
+SCOPES = spec.family_module(BENCH.parent, "dense").SCOPES
 PATH_STAT = 1
 
 
@@ -118,7 +119,7 @@ def trace_dir(tmp_path):
 @pytest.fixture
 def reduced(trace_dir):
     red = trace.reduce(trace_dir, ("bench.step", "bench.wait"))
-    return red, scopes.reduce(trace_dir)
+    return red, scopes.reduce(trace_dir, SCOPES)
 
 
 def test_seconds_per_scope_and_step(reduced):
@@ -176,13 +177,15 @@ def test_innermost_label_edge_cases():
     assert scopes.innermost_label(host, 0, 10) == "engine.sync"
     assert scopes.innermost_label(host, 9, 30) == "no span"
     assert scopes.innermost_label([], 0, 1) == "no span"
-    assert scopes.scope_of("jit(f)/while/body/norm/mlp/add") == "norm"
-    assert scopes.scope_of(None) == scopes.UNSCOPED
-    assert scopes.scope_of("jit(f)/normal/add") == scopes.UNSCOPED
+    assert scopes.scope_of("jit(f)/while/body/norm/mlp/add", SCOPES) == \
+        "norm"
+    assert scopes.scope_of(None, SCOPES) == scopes.UNSCOPED
+    assert scopes.scope_of("jit(f)/normal/add", SCOPES) == scopes.UNSCOPED
 
 
 MODEL = {"n_layers": 2, "d_model": 16, "n_heads": 4, "n_kv_heads": 2,
          "head_dim": 8, "d_ff": 32, "vocab_size": 100}
+COUNTS = spec.family_module(BENCH.parent, "dense").work(MODEL)
 
 
 def _ctx(red):
@@ -192,6 +195,7 @@ def _ctx(red):
                           walls=[1.0, 2.0, 2.5], submit=0.5)
     return SimpleNamespace(
         trace=red, records=[rec], traced=(1.5, 3.0), model=MODEL,
+        work=COUNTS,
         cell=SimpleNamespace(engine={"prefill_chunk": 256}),
         peak=device.peaks("TPU v5 lite"))
 
@@ -204,7 +208,7 @@ def test_readers_on_a_reduction_with_scopes(reduced):
     red, sc = reduced
     ctx = _ctx(dict(red, **sc))
     assert _reader("kv_write_share.longgen")(ctx) == pytest.approx(10.0)
-    _, nbytes = work.norms(MODEL, 2)
+    _, nbytes = work.norms(COUNTS, 2)
     assert _reader("norm_decode_roofline.longgen")(ctx) == pytest.approx(
         100.0 * nbytes / 819e9 / 0.003)
     assert _reader("host_share.longgen")(ctx) == pytest.approx(
@@ -218,3 +222,19 @@ def test_readers_read_nothing_without_scopes(reduced, name):
     red, _ = reduced
     assert _reader(name)(_ctx(red)) is None
     assert _reader(name)(_ctx(None)) is None
+
+
+def test_readers_get_both_reductions_unchanged(trace_dir):
+    """``ctx.trace`` of a traced run holds the scope reduction beside
+    every key of ``trace.reduce``, each value as the two give it."""
+    red = trace.reduce(trace_dir, drive.SPANS)
+    sc = scopes.reduce(trace_dir, SCOPES)
+    got = runner.reduce_trace(trace_dir, SCOPES)
+    assert not set(red) & set(sc)
+    assert set(got) == set(red) | set(sc)
+    assert all(got[k] == v for k, v in red.items())
+    assert all(got[k] == v for k, v in sc.items())
+    ctx = _ctx(got)
+    assert _reader("idle_share.longgen")(ctx) == \
+        _reader("idle_share.longgen")(_ctx(red))
+    assert _reader("kv_write_share.longgen")(ctx) == pytest.approx(10.0)
